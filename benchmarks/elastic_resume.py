@@ -214,6 +214,8 @@ def main(smoke: bool = False, out_dir: str = None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",

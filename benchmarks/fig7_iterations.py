@@ -40,4 +40,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     main()

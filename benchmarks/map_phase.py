@@ -331,6 +331,7 @@ def run_mesh(k: int = 8, n_per_class: int = 80, epochs: int = 2,
     from repro.analysis.hlo import ContractViolation, check_one_all_reduce
     from repro.core import executor
     from repro.launch.hlo_analysis import collective_stats
+    from repro.launch.mesh import make_member_mesh
 
     cfg, ds, lr = _workload(n_per_class)
     if epochs:
@@ -361,7 +362,7 @@ def run_mesh(k: int = 8, n_per_class: int = 80, epochs: int = 2,
 
     sweep = []
     for d in devices:
-        mesh = jax.make_mesh((d,), ("pod",))
+        mesh = make_member_mesh(num_pods=d)
         us = time_call(variant("mesh", mesh), warmup=1, iters=iters)
         res = last["mesh"]
         np.testing.assert_allclose(          # equivalence guard, every config
@@ -379,7 +380,7 @@ def run_mesh(k: int = 8, n_per_class: int = 80, epochs: int = 2,
         })
 
     # the cost model, read off the compiled HLO at the largest mesh
-    mesh = jax.make_mesh((need,), ("pod",))
+    mesh = make_member_mesh(num_pods=need)
     ex = executor.MeshExecutor(mesh=mesh)
     ex._begin(cfg, k)
     params_k = ex._place_params(cnn.init_params(cfg, KEY))
@@ -460,6 +461,8 @@ def main(smoke: bool = False, out_dir: str = None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     import argparse
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",

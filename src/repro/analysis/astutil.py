@@ -36,7 +36,7 @@ TRACE_WRAPPERS = {
     "jax.value_and_grad", "value_and_grad",
     "jax.remat", "remat",
     "jax.checkpoint", "checkpoint",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map",
 }
 TRACE_HOFS = {           # higher-order control flow: fn is the 1st arg
     "lax.scan", "jax.lax.scan", "scan",

@@ -64,10 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                               # jax >= 0.5
-    from jax import shard_map
-except ImportError:                # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.checkpoint import run_state
 from repro.core import elm
@@ -84,6 +81,7 @@ from repro.data.partition import chunk_scan_major, padded_stacked_epoch_batches
 from repro.data.synthetic import one_hot
 from repro.distributed import sharding
 from repro.kernels import resolve_use_pallas
+from repro.launch.mesh import make_member_mesh
 from repro.models import cnn
 
 BACKENDS = ("sequential", "stacked", "mesh")
@@ -969,8 +967,7 @@ class MeshExecutor(_StackedBase):
 
     def _begin(self, cfg, k):
         if self.mesh is None:
-            n = len(jax.devices())
-            self.mesh = jax.make_mesh((n,), ("pod",))
+            self.mesh = make_member_mesh()
         if "pod" not in self.mesh.shape:
             raise ValueError(
                 f"MeshExecutor needs a mesh with a 'pod' axis, got axes "
@@ -1034,7 +1031,10 @@ class MeshExecutor(_StackedBase):
 
     def _snapshot(self, params_k, beta_k):
         """The final UNSHARDED snapshot: gather off-mesh, strip the padded
-        member slots — the only point where member arrays leave the mesh."""
+        member slots. With the averaged model (``_averaged``) the only
+        points where member arrays leave the mesh: what the run hands back
+        lives on one device, where the eval and serving surfaces run (a
+        Pallas kernel cannot be partitioned over a mesh by XLA)."""
         take = lambda a: jnp.asarray(np.asarray(a)[:self._k])
         return StackedMembers(jax.tree.map(take, params_k), take(beta_k))
 
@@ -1076,8 +1076,10 @@ class MeshExecutor(_StackedBase):
             avg_cnn = jax.tree.map(read, num_cnn, params_k)
             avg_beta = read(num_beta, beta_k)
         else:
-            avg_cnn, avg_beta = _mesh_reduce(self.mesh,
-                                             (params_k, beta_k), w)
+            # the Reduce's replicated result, gathered off the mesh
+            avg_cnn, avg_beta = jax.tree.map(
+                lambda a: jnp.asarray(np.asarray(a)),
+                _mesh_reduce(self.mesh, (params_k, beta_k), w))
         return CNNELMModel(avg_cnn, avg_beta)
 
     def _sync(self, params_k, weights, gossip_rounds=None):

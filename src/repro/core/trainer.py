@@ -12,16 +12,12 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.core.averaging import (average_member_dim, broadcast_member_dim,
                                   psum_weighted_mean_members)
 from repro.models import api
 from repro.optim import apply_updates, clip_by_global_norm
-
-try:                               # jax >= 0.5
-    from jax import shard_map
-except ImportError:                # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
 
 
 def make_train_step(cfg, optimizer, lr_schedule,

@@ -30,6 +30,7 @@ import os
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 _TRUE = ("1", "true", "yes", "on")
 _FALSE = ("0", "false", "no", "off")
@@ -66,3 +67,20 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if env is not None:
         return env
     return not on_tpu()
+
+
+def out_vma(*operands) -> frozenset:
+    """The mesh axes a kernel's output varies over: the union of its
+    operands'. A ``pallas_call`` inside ``shard_map`` (``check_vma`` on)
+    needs this on every ``out_shape``; outside one it is empty."""
+    return frozenset().union(*(jax.typeof(a).vma for a in operands))
+
+
+def f32_precision(*operands):
+    """Dot precision inside the kernels: full f32 products when every
+    operand is f32 (the compiler's default may take fewer bf16 passes, and
+    the ELM normal equations are ill-conditioned enough to show it);
+    the default for narrower inputs, which Mosaic refuses to widen."""
+    if all(a.dtype == jnp.float32 for a in operands):
+        return jax.lax.Precision.HIGHEST
+    return None

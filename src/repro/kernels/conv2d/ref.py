@@ -14,14 +14,15 @@ def conv2d_valid_ref(x, w):
 
 
 def im2col(x, kh: int, kw: int):
-    """(B,H,W,C) -> (B*OH*OW, kh*kw*C) patch matrix."""
+    """(B,H,W,C) -> (B*OH*OW, kh*kw*C) patch matrix, columns ordered
+    (kh, kw, C) like an HWIO kernel. Built as kh·kw static slices joined
+    on the channel axis: the TPU compiler spends tens of seconds on a
+    gather form, or on a stack along a new axis, at the 6c-12c shapes
+    (B=200); the slices' transpose is pads, not a scatter."""
     B, H, W, C = x.shape
     OH, OW = H - kh + 1, W - kw + 1
-    idx_h = jnp.arange(OH)[:, None] + jnp.arange(kh)[None, :]
-    idx_w = jnp.arange(OW)[:, None] + jnp.arange(kw)[None, :]
-    patches = x[:, idx_h][:, :, :, idx_w]        # (B,OH,kh,OW,kw,C)
-    patches = patches.transpose(0, 1, 3, 2, 4, 5)  # (B,OH,OW,kh,kw,C)
-    return patches.reshape(B * OH * OW, kh * kw * C)
+    cols = [x[:, i:i + OH, j:j + OW, :] for i in range(kh) for j in range(kw)]
+    return jnp.concatenate(cols, axis=-1).reshape(B * OH * OW, kh * kw * C)
 
 
 def matmul_ref(x, w):

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import resolve_interpret
+from repro.kernels import f32_precision, out_vma, resolve_interpret
 
 BL, BN = 128, 512  # L-tile and n(row)-tile
 
@@ -49,7 +49,8 @@ def _elm_stats_kernel(*refs, nk: int, masked: bool):
     def _zero_u():
         acc_u[...] = jnp.zeros_like(acc_u)
 
-    acc_u[...] += jnp.dot(hi.T, h_j_ref[...],
+    hj = h_j_ref[...]
+    acc_u[...] += jnp.dot(hi.T, hj, precision=f32_precision(hi, hj),
                           preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -63,7 +64,8 @@ def _elm_stats_kernel(*refs, nk: int, masked: bool):
 
     @pl.when(j == 0)
     def _acc_v():
-        acc_v[...] += jnp.dot(hi.T, t_ref[...],
+        t = t_ref[...]
+        acc_v[...] += jnp.dot(hi.T, t, precision=f32_precision(hi, t),
                               preferred_element_type=jnp.float32)
 
     @pl.when((j == 0) & (k == nk - 1))
@@ -94,6 +96,7 @@ def _elm_stats(h, t, mask, *, bl: int, bn: int, interpret: bool):
         mp = jnp.pad(mask.astype(jnp.float32), (0, Np - n))[:, None]
         in_specs.append(pl.BlockSpec((bn, 1), lambda i, j, k: (k, 0)))
         operands.append(mp)
+    vma = out_vma(*operands)
     u, v = pl.pallas_call(
         functools.partial(_elm_stats_kernel, nk=nk, masked=masked),
         grid=(Lp // bl, Lp // bl, nk),
@@ -103,8 +106,8 @@ def _elm_stats(h, t, mask, *, bl: int, bn: int, interpret: bool):
             pl.BlockSpec((bl, Cp), lambda i, j, k: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Lp, Lp), jnp.float32),
-            jax.ShapeDtypeStruct((Lp, Cp), jnp.float32),
+            jax.ShapeDtypeStruct((Lp, Lp), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((Lp, Cp), jnp.float32, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((bl, bl), jnp.float32),
                         pltpu.VMEM((bl, Cp), jnp.float32)],

@@ -1,6 +1,7 @@
 """Pure-jnp oracle for the fused ELM-stats kernel (paper Eq. 3/4)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -11,4 +12,9 @@ def elm_stats_ref(h, t, mask=None):
     hf = h.astype(jnp.float32)
     tf = t.astype(jnp.float32)
     hm = hf if mask is None else hf * mask.astype(jnp.float32)[:, None]
-    return hm.T @ hf, hm.T @ tf
+    # contract the row axis in place: after an explicit transpose XLA lays
+    # H out anew for a one-member batch (a mesh shard) and its GEMM then
+    # sums in another order than the k-member batch on one device
+    rows = (((0,), (0,)), ((), ()))
+    return (jax.lax.dot_general(hm, hf, rows),
+            jax.lax.dot_general(hm, tf, rows))

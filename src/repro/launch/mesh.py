@@ -18,6 +18,7 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
 
 DEFAULT_HOST_DEVICES = 512   # 2x16x16 multi-pod dry-run
 
@@ -45,11 +46,20 @@ def force_host_device_count(n: int | None = None) -> int:
     return n
 
 
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``. jax >= 0.7
+    defaults to Explicit axes, under which the GSPMD placement hints and
+    the shard_map programs of this repo are refused; every mesh the
+    program builds goes through here."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (data, model) or 2x16x16 multi-pod (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
@@ -57,7 +67,7 @@ def make_host_mesh():
     (Under ``force_host_device_count``/``REPRO_HOST_DEVICES`` that is the
     simulated count, not the physical one.)"""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return auto_mesh((n, 1), ("data", "model"))
 
 
 def make_member_mesh(num_pods: int | None = None, *,
@@ -81,12 +91,12 @@ def make_member_mesh(num_pods: int | None = None, *,
                     f"make_member_mesh: {n} devices do not split over "
                     f"hosts={hosts}; pass pods= explicitly")
             pods = n // hosts
-        return jax.make_mesh((hosts, pods), ("host", "pod"))
+        return auto_mesh((hosts, pods), ("host", "pod"))
     if pods is not None:
         raise ValueError("make_member_mesh: pods= requires hosts= "
                          "(use num_pods for the flat 1-D mesh)")
     n = len(jax.devices()) if num_pods is None else num_pods
-    return jax.make_mesh((n,), ("pod",))
+    return auto_mesh((n,), ("pod",))
 
 
 def axis_size(mesh, name: str) -> int:

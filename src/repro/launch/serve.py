@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs.base import get_config, get_reduced_config, replace
 from repro.core import trainer
+from repro.launch.cache import use_compile_cache
 from repro.models import api
 
 
@@ -191,4 +192,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -39,6 +39,7 @@ from repro.configs.base import get_config, get_reduced_config, replace
 from repro.core import trainer
 from repro.core.averaging import average_trees
 from repro.data.lm_data import TokenDatasetSpec, synthetic_token_batches
+from repro.launch.cache import use_compile_cache
 from repro.models import api
 
 # a ~100M-param dense config for the end-to-end example driver
@@ -302,4 +303,5 @@ def replace_args(args):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

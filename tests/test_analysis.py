@@ -30,6 +30,7 @@ from repro.core.averaging import broadcast_member_dim
 from repro.core.cnn_elm import StackedMembers
 from repro.models import cnn
 from repro.serve import BucketedScorer
+from repro.launch.mesh import auto_mesh
 
 ROOT = Path(__file__).resolve().parent.parent
 CFG = get_reduced_config("cnn_elm_6c12c")
@@ -394,7 +395,7 @@ def test_audit_stacked_backend_green():
                     reason="mesh audit needs "
                            "XLA_FLAGS=--xla_force_host_platform_device_count=8")
 def test_audit_mesh_backend_green():
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = auto_mesh((8,), ("pod",))
     for report in hlo.audit_executor(CFG, "mesh", mesh=mesh, k=3):
         assert report.ok, str(report)
 
@@ -407,12 +408,12 @@ def test_audit_hierarchical_mesh_expects_two_allreduces():
     to ``check_two_all_reduces`` — green on the real programs, and the
     check itself FAILS a one-collective program (so the two-collective
     bar can't silently pass on the flat lowering)."""
-    mesh = jax.make_mesh((2, 4), ("host", "pod"))
+    mesh = auto_mesh((2, 4), ("host", "pod"))
     reports = hlo.audit_executor(CFG, "mesh", mesh=mesh, k=3)
     for report in reports:
         assert report.ok, str(report)
     # a single-psum program must FAIL the two-collective check
-    flat = jax.make_mesh((8,), ("pod",))
+    flat = auto_mesh((8,), ("pod",))
     from repro.core import executor as ex_mod
     ex = ex_mod.MeshExecutor(mesh=flat)
     ex._begin(CFG, 3)
@@ -430,7 +431,7 @@ def test_audit_average_step_plain_green():
                     reason="mesh audit needs "
                            "XLA_FLAGS=--xla_force_host_platform_device_count=8")
 def test_audit_average_step_mesh_green():
-    mesh = jax.make_mesh((8,), ("pod",))
+    mesh = auto_mesh((8,), ("pod",))
     report = hlo.audit_average_step(mesh=mesh, weights=[1.0] * 8)
     assert report.ok, str(report)
 

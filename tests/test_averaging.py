@@ -6,6 +6,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.averaging import (average_member_dim, average_trees,
                                   broadcast_member_dim, weighted_average_trees)
+from repro.launch.mesh import auto_mesh
 
 RNG = np.random.default_rng(7)
 
@@ -107,13 +108,10 @@ def test_psum_weighted_mean_members_single_collective_semantics():
     contract)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.core.averaging import psum_weighted_mean_members
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("pod",))
+    mesh = auto_mesh((n_dev,), ("pod",))
     k = 2 * n_dev
     ms = [_tree(200 + i) for i in range(k)]
     stacked = jax.tree.map(lambda *a: jnp.stack(a), *ms)
@@ -148,9 +146,9 @@ def test_make_average_step_mesh_validates_contract():
     from repro.core import trainer
 
     with pytest.raises(ValueError, match="'pod' axis"):
-        trainer.make_average_step(mesh=jax.make_mesh((1,), ("data",)))
+        trainer.make_average_step(mesh=auto_mesh((1,), ("data",)))
     n = len(jax.devices())
-    step = trainer.make_average_step(mesh=jax.make_mesh((n,), ("pod",)))
+    step = trainer.make_average_step(mesh=auto_mesh((n,), ("pod",)))
     if n > 1:   # with 1 pod every member count divides
         with pytest.raises(ValueError, match="do not divide"):
             step({"w": jnp.zeros((n + 1, 3))})

@@ -7,6 +7,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import e2lm, elm
 from repro.layers.norms import optimal_tanh
+from repro.launch.mesh import auto_mesh
 
 RNG = np.random.default_rng(42)
 
@@ -97,12 +98,9 @@ def test_optimal_tanh_constants():
 def test_psum_stats_inside_shard_map():
     """E²LM map inside SPMD: per-device partial stats + one psum == global."""
     from jax.sharding import PartitionSpec as P
-    try:                               # jax >= 0.5
-        from jax import shard_map
-    except ImportError:                # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = auto_mesh((n_dev,), ("data",))
     n = 8 * n_dev
     h = jnp.asarray(RNG.normal(size=(n, 6)).astype(np.float32))
     t = jnp.asarray(RNG.normal(size=(n, 2)).astype(np.float32))

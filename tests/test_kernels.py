@@ -59,6 +59,34 @@ def test_blocked_matmul_property(m, k, n):
                                rtol=1e-4, atol=1e-4)
 
 
+def test_features_grad_through_pallas_matches_xla():
+    """jax.grad of the ELM loss through the Pallas conv (custom VJP, two
+    more blocked GEMMs) equals the XLA-conv gradient at the 6c-12c width."""
+    from repro.configs.base import get_config
+    from repro.core import elm
+    from repro.models import cnn
+    cfg = get_config("cnn_elm_6c12c")
+    rng = np.random.default_rng(0)
+    params = cnn.init_params(cfg, jax.random.PRNGKey(0))
+    x = jnp.asarray(rng.random((4, 28, 28)).astype(np.float32))
+    beta = jnp.asarray(rng.normal(
+        size=(cnn.feature_dim(cfg), cfg.num_classes)).astype(np.float32))
+    t = jax.nn.one_hot(jnp.arange(4) % cfg.num_classes, cfg.num_classes)
+
+    def loss(p, use_pallas):
+        h = cnn.features(cfg, p, x, use_pallas=use_pallas)
+        return elm.elm_loss(h, beta, t)
+
+    g_pl = jax.grad(loss)(params, True)
+    g_ref = jax.grad(loss)(params, False)
+    for a, b in zip(jax.tree.leaves(g_pl), jax.tree.leaves(g_ref)):
+        # f32 sums in another order: 1e-5 of each leaf's own scale, so
+        # near-zero entries are judged against the gradient they sit in
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-5,
+                                   atol=1e-5 * np.abs(b).max())
+
+
 def test_im2col_decomposition():
     """conv == im2col + matmul (the kernel's structural claim)."""
     x = _rand(2, 10, 10, 3)

@@ -21,6 +21,7 @@ from repro.data.partition import (Partition, batches, chunk_scan_major,
 from repro.data.synthetic import make_extended_mnist
 from repro.models import cnn
 from repro.optim.schedules import dynamic_paper
+from repro.launch.mesh import auto_mesh
 
 CFG = get_reduced_config("cnn_elm_6c12c")
 KEY = jax.random.PRNGKey(0)
@@ -179,6 +180,24 @@ def test_stacked_equivalent_sgd_epochs(parts):
                              atol_params=1e-6)
 
 
+@pytest.mark.parametrize("backend", ["sequential", "stacked"])
+def test_sgd_epoch_through_pallas_conv(parts, backend):
+    """One SGD epoch with ``use_pallas=True`` — the kernel path a TPU takes
+    (here interpreted): the conv kernel's custom VJP drives Alg. 2 line 13,
+    and members track the XLA-conv run to the SGD tolerance."""
+    cfg = replace(CFG, elm_lambda=1.0)
+    lr = dynamic_paper(0.05)
+    runs = [AveragingRun(cfg, MapConfig(epochs=1, lr_schedule=lr,
+                                        batch_size=32, backend=backend,
+                                        use_pallas=up)).run(parts, KEY)
+            for up in (False, True)]
+    ref, pallas = runs
+    for a, b in zip(ref.members + [ref.averaged],
+                    pallas.members + [pallas.averaged]):
+        _assert_models_close(a, b, rtol=1e-4, atol_beta=2e-5,
+                             atol_params=1e-6)
+
+
 def test_stacked_members_api(parts):
     sm = cnn_elm.train_members_stacked(CFG, cnn.init_params(CFG, KEY), parts,
                                        epochs=0, lr_schedule=None,
@@ -198,7 +217,7 @@ def test_stacked_members_api(parts):
 def test_stacked_with_mesh(parts):
     """member_dim_shardings placement keeps the stacked path equivalent on a
     1-device 'pod' mesh (degenerate but exercises the SPMD plumbing)."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = auto_mesh((1,), ("pod",))
     init = cnn.init_params(CFG, KEY)
     plain = cnn_elm.train_members_stacked(CFG, init, parts, epochs=0,
                                           lr_schedule=None, batch_size=32)

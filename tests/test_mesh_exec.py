@@ -32,6 +32,7 @@ from repro.analysis.hlo import (audit_executor, check_donation,
                                 check_no_collectives, check_one_all_reduce)
 from repro.models import cnn
 from repro.optim.schedules import dynamic_paper
+from repro.launch.mesh import auto_mesh
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8,
@@ -50,7 +51,7 @@ def ds():
 
 
 def _mesh(pods):
-    return jax.make_mesh((pods,), ("pod",))
+    return auto_mesh((pods,), ("pod",))
 
 
 def _members_bit_equal(a_members, b_members):
@@ -77,6 +78,11 @@ def test_mesh_equals_stacked_elm_only(ds, k, pods):
     np.testing.assert_allclose(np.asarray(st.averaged.beta),
                                np.asarray(me.averaged.beta),
                                rtol=1e-5, atol=1e-6)
+    # members and the averaged model leave the mesh: one device each, for
+    # the eval and serving surfaces (XLA cannot partition a Pallas kernel)
+    for leaf in jax.tree.leaves((me.stacked.cnn_params, me.stacked.beta,
+                                 me.averaged.cnn_params, me.averaged.beta)):
+        assert len(leaf.sharding.device_set) == 1, leaf.sharding
 
 
 def test_mesh_equals_stacked_sgd(ds):
@@ -153,13 +159,13 @@ def test_mesh_2d_extra_axes(ds):
     parts = partition_iid(ds.x, ds.y, k=4, seed=0)
     st = AveragingRun(CFG, MapConfig(epochs=0, batch_size=32)).run(parts, KEY)
     me = AveragingRun(CFG, MapConfig(epochs=0, batch_size=32, backend="mesh",
-                                     mesh=jax.make_mesh((4, 2),
+                                     mesh=auto_mesh((4, 2),
                                                         ("pod", "data")))
                       ).run(parts, KEY)
     _members_bit_equal(st.members, me.members)
     with pytest.raises(ValueError, match="'pod' axis"):
         AveragingRun(CFG, MapConfig(epochs=0, batch_size=32, backend="mesh",
-                                    mesh=jax.make_mesh((8,), ("data",)))
+                                    mesh=auto_mesh((8,), ("data",)))
                      ).run(parts, KEY)
 
 

@@ -400,15 +400,21 @@ def test_hot_reload_swaps_with_zero_drops():
         stop.set()
         th.join()
 
+        # drain the traffic, then one probe in flight at a time: each is a
+        # batch of its own at the 1-row bucket. The comparison must score
+        # at the served shape — XLA's CPU GEMM rounds a 1-row bucket
+        # differently from an 8-row one.
+        for f in futs:
+            f.result(timeout=30)
         probe = test.x[:7]
-        post = np.stack([f.result(timeout=30).member_scores
-                         for f in [srv.submit(img) for img in probe]],
-                        axis=1)
+        post = np.stack([srv.submit(img).result(timeout=30).member_scores
+                         for img in probe], axis=1)
         srv.close()
         watcher.stop()
-        direct = BucketedScorer(
-            cfg, run_state.restore_round(d, 1).members,
-            max_batch=8).score_block(probe)
+        fresh = BucketedScorer(cfg, run_state.restore_round(d, 1).members,
+                               max_batch=8)
+        direct = np.concatenate([fresh.score_block(img[None])
+                                 for img in probe], axis=1)
         assert np.array_equal(post, direct)      # bit-equal, not allclose
         assert all(f.exception(timeout=10) is None for f in futs)
         stats = srv.stats()

@@ -8,6 +8,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ARCH_IDS, get_config, get_reduced_config
 from repro.distributed import sharding
 from repro.models import api
+from repro.launch.mesh import auto_mesh
 
 LM_ARCHS = [a for a in ARCH_IDS if not a.startswith("cnn_elm")]
 
@@ -79,7 +80,7 @@ def test_member_resolve_rules():
 def test_member_and_batch_specs_match_shardings():
     """The spec-level twins (shard_map in/out_specs) must agree exactly
     with the NamedSharding builders they mirror."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = auto_mesh((1,), ("pod",))
     tree = {"w": jnp.zeros((4, 5, 3)), "b": jnp.zeros((4,))}
     specs = sharding.member_dim_specs(tree, mesh)
     shardings_ = sharding.member_dim_shardings(tree, mesh)
@@ -97,14 +98,14 @@ def test_stacked_batch_shardings_member_axis():
     """Scan-major batch arrays (nb, k, B, ...) shard the member dim (axis 1)
     on 'pod' — the chunked host→device pipeline's placement — with the
     usual replication fallback when k doesn't divide the pod count."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = auto_mesh((1,), ("pod",))
     xb = jnp.zeros((4, 3, 8, 5, 5))
     mb = jnp.zeros((4, 3))
     out = sharding.stacked_batch_shardings((xb, mb), mesh)
     assert out[0].spec == P(None, "pod", None, None, None)
     assert out[1].spec == P(None, "pod")
     # a mesh without a 'pod' axis replicates (the fallback contract)
-    mesh2 = jax.make_mesh((1,), ("data",))
+    mesh2 = auto_mesh((1,), ("data",))
     out2 = sharding.stacked_batch_shardings((jnp.zeros((4, 5)),), mesh2)
     assert out2[0].spec == P(None, None)
 

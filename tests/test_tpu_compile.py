@@ -1,0 +1,137 @@
+"""Compile the CNN-ELM hot path for a described TPU v5e, with no chip.
+
+The TPU compiler is installed with jaxlib and compiles for a topology that
+is described and not attached, so these tests catch what interpret mode
+cannot: Mosaic refusing a block shape, a kernel without a VJP, a
+``pallas_call`` without ``vma`` inside ``shard_map``. Shapes are the
+published 6c-2s-12c-2s width at B=200. Each compiled program must hold the
+Pallas kernels (``tpu_custom_call``), so a silent fall back to the XLA conv
+fails here.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library at a time, and every pytest-xdist
+worker imports this file. Keep these tests in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.configs.base import get_config
+from repro.core import cnn_elm, elm, executor
+from repro.kernels.conv2d.kernel import blocked_matmul
+from repro.kernels.elm_stats.kernel import elm_stats
+from repro.models import cnn
+
+CFG = get_config("cnn_elm_6c12c")
+B, K_MEMBERS, NB = 200, 4, 2
+F, C = cnn.feature_dim(CFG), CFG.num_classes
+# the two conv GEMMs of 6c-12c: conv1 (B·24·24, 5·5·1)@(25, 6) and
+# conv2 (B·8·8, 5·5·6)@(150, 12)
+GEMMS = [(B * 576, 25, 6), (B * 64, 150, 12)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:    # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compiled_kernels(monkeypatch):
+    """The auto policy sees the CPU backend here; steer it to the compiled
+    kernels. A chip-less compile cannot be read back from the persistent
+    cache, so keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _custom_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _sds(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _member_params(sharding):
+    shapes = jax.eval_shape(lambda: cnn.init_params(CFG, jax.random.PRNGKey(0)))
+    return jax.tree.map(lambda a: _sds((K_MEMBERS,) + a.shape, sharding),
+                        shapes)
+
+
+@pytest.mark.parametrize("m,k,n", GEMMS)
+def test_conv_gemm_forward_and_vjp_compile(one_chip, m, k, n):
+    x, w = _sds((m, k), one_chip), _sds((k, n), one_chip)
+    fwd = jax.jit(blocked_matmul).lower(x, w).compile()
+    assert _custom_calls(fwd) == 1
+    bwd = jax.jit(jax.grad(lambda a, b: blocked_matmul(a, b).sum(),
+                           argnums=(0, 1))).lower(x, w).compile()
+    assert _custom_calls(bwd) == 2          # dX = G·Wᵀ and dW = Xᵀ·G
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_elm_stats_compile(one_chip, masked):
+    h, t = _sds((B, F), one_chip), _sds((B, C), one_chip)
+    mask = _sds((B,), one_chip) if masked else None
+    fn = jax.jit(elm_stats)
+    assert _custom_calls(fn.lower(h, t, mask).compile()) == 1
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_stacked_epoch_with_sgd_compiles(one_chip, masked):
+    """Alg. 2 lines 9-14 for k=4 members: features, stats, β solve and the
+    SGD backward through the conv kernel, one scan."""
+    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), one_chip),
+                         _sds((K_MEMBERS, F, C), one_chip),
+                         _sds((K_MEMBERS,), one_chip))
+    lowered = cnn_elm._stacked_epoch.lower(
+        CFG, _member_params(one_chip), stats,
+        _sds((NB, K_MEMBERS, B, 28, 28), one_chip),
+        _sds((NB, K_MEMBERS, B, C), one_chip),
+        _sds((NB, K_MEMBERS), one_chip), _sds((), one_chip),
+        solve_each_batch=True, use_pallas=True, masked=masked)
+    # 2 conv forwards for the stats, 2 for the loss, 2·2 backward GEMMs
+    # (the first conv's dX is dead and may be pruned), 1 elm_stats
+    assert _custom_calls(lowered.compile()) >= 7
+
+
+def test_mesh_epoch_compiles_on_four_chips(topo):
+    """The mesh backend's epoch, shard_map-ed over 4 described chips with
+    ``check_vma`` on: the kernels need ``vma`` on their out_shapes."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("pod",),
+                axis_types=(AxisType.Auto,))
+    pod = NamedSharding(mesh, P("pod"))
+    per_batch = NamedSharding(mesh, P(None, "pod"))
+    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), pod),
+                         _sds((K_MEMBERS, F, C), pod),
+                         _sds((K_MEMBERS,), pod))
+    lowered = executor._mesh_epoch.lower(
+        CFG, mesh, _member_params(pod), stats,
+        _sds((NB, K_MEMBERS, B, 28, 28), per_batch),
+        _sds((NB, K_MEMBERS, B, C), per_batch),
+        _sds((NB, K_MEMBERS), per_batch),
+        _sds((), NamedSharding(mesh, P())),
+        solve_each_batch=True, use_pallas=True, masked=False)
+    compiled = lowered.compile()
+    assert _custom_calls(compiled) >= 7
+    assert "all-reduce" not in compiled.as_text()     # members independent
