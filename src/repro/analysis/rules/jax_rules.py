@@ -120,6 +120,27 @@ def host_rng_or_clock(ctx):
                    f"jax.random with an explicit key (the seed + i rule)")
 
 
+_SPAN_CALLS = {"TraceAnnotation", "StepTraceAnnotation"}
+
+
+@rule("host-span-in-traced",
+      "no profiler host spans (TraceAnnotation/StepTraceAnnotation) "
+      "inside traced functions — the span opens once, at trace time, "
+      "and records nothing per step; scope device code with "
+      "jax.named_scope and open spans around the dispatch")
+def host_span_in_traced(ctx):
+    for node in ast.walk(ctx.tree):
+        if not isinstance(node, ast.Call) or not ctx.traced.in_traced(node):
+            continue
+        name = dotted(node.func)
+        if name is not None and name.split(".")[-1] in _SPAN_CALLS:
+            yield (node.lineno, node.col_offset,
+                   f"host span `{name}(...)` inside a traced function "
+                   f"fires once while tracing — use jax.named_scope "
+                   f"inside, or open the span on the host around the "
+                   f"call")
+
+
 @rule("sub-f32-accum",
       "averaged/reduced trees must accumulate in f32 or wider — a bf16 "
       "running sum drifts O(k·2^-8) off the true mean (the PR 2 "

@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 
+from repro import scopes
+
 
 def average_trees(members: Sequence):
     """Uniform mean, accumulated in f32 regardless of leaf dtype: a bf16
@@ -73,15 +75,18 @@ def average_member_dim(stacked_params, weights=None):
     ``weighted_average_trees``; accumulation is f32 either way. This is the
     Reduce applied both at the end of a run and at every multi-round sync
     (``trainer.make_average_step`` / ``runner.ReduceConfig(rounds=r)``)."""
-    if weights is None:
+    with jax.named_scope(scopes.REDUCE):
+        if weights is None:
+            return jax.tree.map(
+                lambda a: jnp.mean(a.astype(jnp.float32),
+                                   axis=0).astype(a.dtype),
+                stacked_params)
+        w = jnp.asarray(weights, jnp.float32)
+        w = w / jnp.sum(w)
         return jax.tree.map(
-            lambda a: jnp.mean(a.astype(jnp.float32), axis=0).astype(a.dtype),
+            lambda a: jnp.tensordot(w, a.astype(jnp.float32),
+                                    axes=1).astype(a.dtype),
             stacked_params)
-    w = jnp.asarray(weights, jnp.float32)
-    w = w / jnp.sum(w)
-    return jax.tree.map(
-        lambda a: jnp.tensordot(w, a.astype(jnp.float32), axes=1).astype(a.dtype),
-        stacked_params)
 
 
 def broadcast_member_dim(params, k: int):

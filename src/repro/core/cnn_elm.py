@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import scopes
 from repro.core import elm
 from repro.core.averaging import (average_member_dim, average_trees,
                                   weighted_average_trees)
@@ -88,8 +89,9 @@ def _sgd_step(cfg, cnn_params, beta, x, t, lr, *,
         h = cnn.features(cfg, p, x, use_pallas=use_pallas)
         return elm.elm_loss(h, beta, t)
 
-    val, grads = jax.value_and_grad(loss)(cnn_params)
-    new = jax.tree.map(lambda p, g: p - lr * g, cnn_params, grads)
+    with jax.named_scope(scopes.SGD_UPDATE):
+        val, grads = jax.value_and_grad(loss)(cnn_params)
+        new = jax.tree.map(lambda p, g: p - lr * g, cnn_params, grads)
     return new, val
 
 
@@ -208,13 +210,15 @@ def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
                 hp = cnn.features(cfg, p, x, use_pallas=use_pallas)
                 return elm.elm_loss(hp, beta, t)
 
-            grads = jax.grad(loss)(params)
-            if masked:
-                params = jax.tree.map(
-                    lambda p, g: jnp.where(m > 0, p - lr * g, p),
-                    params, grads)
-            else:
-                params = jax.tree.map(lambda p, g: p - lr * g, params, grads)
+            with jax.named_scope(scopes.SGD_UPDATE):
+                grads = jax.grad(loss)(params)
+                if masked:
+                    params = jax.tree.map(
+                        lambda p, g: jnp.where(m > 0, p - lr * g, p),
+                        params, grads)
+                else:
+                    params = jax.tree.map(lambda p, g: p - lr * g, params,
+                                          grads)
         return params, stats
 
     def body(carry, batch):

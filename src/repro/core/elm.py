@@ -16,6 +16,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.kernels.elm_stats import ops as stats_ops
 from repro.layers.norms import optimal_tanh
 
@@ -87,15 +88,16 @@ def _cho_solve_beta(u, v, lam: float) -> jax.Array:
     per-batch SGD steps — one shared lowering keeps the sequential reference
     and the vmapped stacked Map phase numerically identical."""
     L = u.shape[-1]
-    a = u + jnp.eye(L, dtype=jnp.float32) / lam
-    batched = a.ndim == 3
-    if not batched:
-        a, v = a[None], v[None]
-    f = jax.lax.linalg.cholesky(a)
-    y = jax.lax.linalg.triangular_solve(f, v, left_side=True, lower=True)
-    b = jax.lax.linalg.triangular_solve(f, y, left_side=True, lower=True,
-                                        transpose_a=True)
-    return b if batched else b[0]
+    with jax.named_scope(scopes.BETA_SOLVE):
+        a = u + jnp.eye(L, dtype=jnp.float32) / lam
+        batched = a.ndim == 3
+        if not batched:
+            a, v = a[None], v[None]
+        f = jax.lax.linalg.cholesky(a)
+        y = jax.lax.linalg.triangular_solve(f, v, left_side=True, lower=True)
+        b = jax.lax.linalg.triangular_solve(f, y, left_side=True, lower=True,
+                                            transpose_a=True)
+        return b if batched else b[0]
 
 
 def solve_beta(stats: ELMStats, lam: float) -> jax.Array:
@@ -114,9 +116,10 @@ def elm_loss(h, beta, t, *, activation: bool = True):
 
 
 def predict(h, beta, *, activation: bool = True):
-    if activation:
-        h = optimal_tanh(h)
-    return h.astype(jnp.float32) @ beta
+    with jax.named_scope(scopes.READOUT):
+        if activation:
+            h = optimal_tanh(h)
+        return h.astype(jnp.float32) @ beta
 
 
 def accuracy(scores, labels):

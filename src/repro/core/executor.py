@@ -37,8 +37,11 @@ invisible; the final snapshot strips them. Simulate pods on CPU with
 Telemetry contract (a plain dict, shared with the runner's ``RunResult``):
 ``dispatches`` counts every device program the executor launches (epoch
 chunks, β solves, syncs); ``round_syncs`` the inter-round average+broadcast
-programs; ``reduce_dispatches`` (mesh only) the one-collective Reduce
-programs behind each ``averaged()``.
+programs.
+
+Tracing: the programs carry the device scopes of ``repro.scopes``, and
+``_StackedBase.execute`` opens the host spans of the stacked loop once,
+for both stacked executors.
 
 Fault tolerance (``plan.checkpoint`` / ``plan.start_round`` /
 ``plan.completed``): the stacked layouts save one atomic
@@ -65,7 +68,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax import shard_map
+from jax.profiler import TraceAnnotation
 
+from repro import scopes
 from repro.checkpoint import run_state
 from repro.core import elm
 from repro.core.averaging import (average_member_dim, broadcast_member_dim,
@@ -367,8 +372,9 @@ def _round_sync(params_k, weights):
     mean over the member dim, broadcast back as every member's next-round
     init. Jitted so the one-dispatch-per-sync telemetry is literal."""
     k = jax.tree.leaves(params_k)[0].shape[0]
-    return broadcast_member_dim(
-        average_member_dim(params_k, weights=weights), k)
+    with jax.named_scope(scopes.REDUCE):
+        return broadcast_member_dim(
+            average_member_dim(params_k, weights=weights), k)
 
 
 @functools.partial(jax.jit, static_argnames=("rounds",))
@@ -376,7 +382,8 @@ def _gossip_round_sync(params_k, weights, *, rounds: int):
     """The single-device GOSSIP sync: ring mixing over the member dim,
     every member reset to its OWN consensus iterate (not one broadcast
     row — the decentralized regime)."""
-    return gossip_member_dim(params_k, weights, rounds)[0]
+    with jax.named_scope(scopes.REDUCE):
+        return gossip_member_dim(params_k, weights, rounds)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("rounds",))
@@ -384,7 +391,8 @@ def _gossip_reduce(tree, weights, *, rounds: int):
     """The single-device gossip Reduce: the published ratio-of-sums
     readout after ``rounds`` mixing rounds (exact weighted mean up to
     f32 summation order — the mixing stencil is sum-invariant)."""
-    return gossip_member_dim(tree, weights, rounds)[1]
+    with jax.named_scope(scopes.REDUCE):
+        return gossip_member_dim(tree, weights, rounds)[1]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "use_pallas"))
@@ -488,24 +496,35 @@ class _StackedBase:
             self.name, partitions, seed=plan.seed, epochs=plan.epochs,
             rounds=plan.rounds, batch_size=plan.batch_size)
             if ck is not None else None)
+        def put(chunk):
+            with TraceAnnotation(scopes.MAP_PUT):
+                return self._put_chunk(chunk)
+
+        def gather(stats_k):
+            with TraceAnnotation(scopes.MAP_GATHER):
+                return self._host_stats(stats_k)
+
         for r, passes in enumerate(round_passes):
             if r < plan.start_round:
                 continue        # completed before the resume point; the
             stats_k = None      # rng draws were burned above
             for solve_each_batch, lr in passes:
-                xb, tb, mb, chunk = self._epoch_arrays(
-                    partitions, plan.batch_size, rngs, C, plan.chunk_batches)
+                with TraceAnnotation(scopes.MAP_EPOCH_BUILD):
+                    xb, tb, mb, chunk = self._epoch_arrays(
+                        partitions, plan.batch_size, rngs, C,
+                        plan.chunk_batches)
                 masked = bool(np.any(mb == 0.0))
                 stats_k = self._zero_stats(F, C)
                 chunks = chunk_scan_major((xb, tb, mb), chunk)
                 lr_dev = jnp.asarray(lr, jnp.float32)
-                nxt = self._put_chunk(chunks[0])
+                nxt = put(chunks[0])
                 for i in range(len(chunks)):
-                    cur, nxt = nxt, (self._put_chunk(chunks[i + 1])
+                    cur, nxt = nxt, (put(chunks[i + 1])
                                      if i + 1 < len(chunks) else None)
-                    params_k, stats_k = self._epoch_dispatch(
-                        cfg, params_k, stats_k, cur, lr_dev,
-                        solve_each_batch, use_pallas, masked)
+                    with TraceAnnotation(scopes.MAP_DISPATCH):
+                        params_k, stats_k = self._epoch_dispatch(
+                            cfg, params_k, stats_k, cur, lr_dev,
+                            solve_each_batch, use_pallas, masked)
                     _bump(telemetry)
             last = r == len(round_passes) - 1
             snapshot, averaged, weights = self._round_closures(
@@ -513,8 +532,10 @@ class _StackedBase:
             if last:
                 sm = snapshot()
             else:
-                params_k = self._sync(params_k, weights(),
-                                      gossip_rounds=plan.gossip_rounds)
+                w = weights()
+                with TraceAnnotation(scopes.MAP_REDUCE):
+                    params_k = self._sync(params_k, w,
+                                          gossip_rounds=plan.gossip_rounds)
                 # the sync is a device dispatch too — counted toward the
                 # total AND tallied separately, before on_round closes this
                 # round's books, so per-round telemetry prices its own sync
@@ -531,7 +552,7 @@ class _StackedBase:
                                           params_k)
                 path = run_state.save_round(
                     ck.dir, r, members=snapshot(),
-                    stats=self._host_stats(stats_k), averaged=averaged(),
+                    stats=gather(stats_k), averaged=averaged(),
                     resume_params=resume,
                     meta={**ck_meta, "round": r,
                           "epochs_done": (r + 1) * per_round,
@@ -540,7 +561,7 @@ class _StackedBase:
                     ck.after_save("round", r, path)
             if plan.on_round is not None:
                 plan.on_round(r, snapshot, averaged)
-        return MapOutcome(sm.unstack(), sm, self._host_stats(stats_k))
+        return MapOutcome(sm.unstack(), sm, gather(stats_k))
 
     def _round_closures(self, cfg, params_k, stats_k, plan, r, use_pallas,
                         telemetry):
@@ -586,9 +607,11 @@ class _StackedBase:
 
         def averaged():
             if "avg" not in cache:
-                cache["avg"] = self._averaged(
-                    params_k, solved_beta(), weights(), telemetry,
-                    gossip_rounds=plan.gossip_rounds)
+                beta_k, w = solved_beta(), weights()
+                with TraceAnnotation(scopes.MAP_REDUCE):
+                    cache["avg"] = self._averaged(
+                        params_k, beta_k, w, telemetry,
+                        gossip_rounds=plan.gossip_rounds)
             return cache["avg"]
 
         return snapshot, averaged, weights
@@ -825,7 +848,8 @@ def _mesh_reduce(mesh, tree, weights):
     replicated output. ``weights`` is the full padded member-weight
     vector — zeros drop padded members exactly."""
     def local(t, w):
-        return _psum_weighted_mean(t, w, mesh)
+        with jax.named_scope(scopes.REDUCE):
+            return _psum_weighted_mean(t, w, mesh)
 
     return shard_map(local, mesh=mesh,
                      in_specs=(_member_specs(tree, mesh),
@@ -845,9 +869,10 @@ def _mesh_sync(mesh, params_k, weights):
     pspecs = _member_specs(params_k, mesh)
 
     def local(p, w):
-        avg = _psum_weighted_mean(p, w, mesh)
-        k_local = jax.tree.leaves(p)[0].shape[0]
-        return broadcast_member_dim(avg, k_local)
+        with jax.named_scope(scopes.REDUCE):
+            avg = _psum_weighted_mean(p, w, mesh)
+            k_local = jax.tree.leaves(p)[0].shape[0]
+            return broadcast_member_dim(avg, k_local)
 
     return shard_map(local, mesh=mesh,
                      in_specs=(pspecs, P(_member_axis_entry(mesh))),
@@ -893,13 +918,14 @@ def _mesh_gossip_sync(mesh, params_k, weights, *, rounds: int):
     p = mesh.shape["pod"]
 
     def local(prm, w):
-        num, den = gossip_ring_mix(prm, w, "pod", rounds, p)
-        ref = jax.tree.map(lambda a: a[0], prm)
-        est = jax.tree.map(
-            lambda s, t: (s / jnp.maximum(den, 1e-30)).astype(t.dtype),
-            num, ref)
-        k_local = jax.tree.leaves(prm)[0].shape[0]
-        return broadcast_member_dim(est, k_local)
+        with jax.named_scope(scopes.REDUCE):
+            num, den = gossip_ring_mix(prm, w, "pod", rounds, p)
+            ref = jax.tree.map(lambda a: a[0], prm)
+            est = jax.tree.map(
+                lambda s, t: (s / jnp.maximum(den, 1e-30)).astype(t.dtype),
+                num, ref)
+            k_local = jax.tree.leaves(prm)[0].shape[0]
+            return broadcast_member_dim(est, k_local)
 
     return shard_map(local, mesh=mesh,
                      in_specs=(pspecs, P("pod")),
@@ -916,9 +942,10 @@ def _mesh_gossip_state(mesh, tree, weights, *, rounds: int):
     ``sum(num)/sum(den)`` for the published model — sums the mixing
     stencil leaves invariant."""
     def local(t, w):
-        num, den = gossip_ring_mix(t, w, "pod", rounds,
-                                   mesh.shape["pod"])
-        return jax.tree.map(lambda a: a[None], num), den[None]
+        with jax.named_scope(scopes.REDUCE):
+            num, den = gossip_ring_mix(t, w, "pod", rounds,
+                                       mesh.shape["pod"])
+            return jax.tree.map(lambda a: a[None], num), den[None]
 
     num_specs = jax.tree.map(
         lambda a: P(*(("pod",) + (None,) * (a.ndim - 1))), tree)
@@ -1063,7 +1090,6 @@ class MeshExecutor(_StackedBase):
     def _averaged(self, params_k, beta_k, weights, telemetry,
                   gossip_rounds=None):
         _bump(telemetry)
-        _bump(telemetry, key="reduce_dispatches")
         w = self._weights_dev(weights)
         if gossip_rounds is not None:
             num, den = _mesh_gossip_state(

@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.kernels import resolve_interpret, resolve_use_pallas
 from repro.kernels.conv2d import ref
 from repro.kernels.conv2d.kernel import blocked_matmul
@@ -22,15 +23,16 @@ from repro.kernels.conv2d.kernel import blocked_matmul
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def _conv2d_valid(x, w, *, use_pallas: bool, interpret: bool):
-    if not use_pallas:
-        return ref.conv2d_valid_ref(x, w).astype(x.dtype)
-    B, H, W, C = x.shape
-    kh, kw, _, Cout = w.shape
-    OH, OW = H - kh + 1, W - kw + 1
-    patches = ref.im2col(x, kh, kw)                  # (B*OH*OW, kh*kw*C)
-    wmat = w.reshape(kh * kw * C, Cout)
-    out = blocked_matmul(patches, wmat, interpret=interpret)
-    return out.reshape(B, OH, OW, Cout).astype(x.dtype)
+    with jax.named_scope(scopes.CONV2D):
+        if not use_pallas:
+            return ref.conv2d_valid_ref(x, w).astype(x.dtype)
+        B, H, W, C = x.shape
+        kh, kw, _, Cout = w.shape
+        OH, OW = H - kh + 1, W - kw + 1
+        patches = ref.im2col(x, kh, kw)              # (B*OH*OW, kh*kw*C)
+        wmat = w.reshape(kh * kw * C, Cout)
+        out = blocked_matmul(patches, wmat, interpret=interpret)
+        return out.reshape(B, OH, OW, Cout).astype(x.dtype)
 
 
 def conv2d_valid(x, w, *, use_pallas: Optional[bool] = None):

@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax
 
+from repro import scopes
 from repro.kernels import resolve_interpret, resolve_use_pallas
 from repro.kernels.elm_stats import ref
 from repro.kernels.elm_stats.kernel import elm_stats as _pallas_stats
@@ -18,9 +19,10 @@ from repro.kernels.elm_stats.kernel import elm_stats as _pallas_stats
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret"))
 def _elm_stats(h, t, mask, *, use_pallas: bool, interpret: bool):
-    if use_pallas:
-        return _pallas_stats(h, t, mask, interpret=interpret)
-    return ref.elm_stats_ref(h, t, mask)
+    with jax.named_scope(scopes.ELM_STATS):
+        if use_pallas:
+            return _pallas_stats(h, t, mask, interpret=interpret)
+        return ref.elm_stats_ref(h, t, mask)
 
 
 def elm_stats(h, t, *, mask=None, use_pallas: Optional[bool] = None):

@@ -30,7 +30,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import scopes
 from repro.core import elm
 from repro.core.cnn_elm import StackedMembers
 from repro.kernels import resolve_use_pallas
@@ -149,10 +151,13 @@ class BucketedScorer:
     def score_block(self, x) -> np.ndarray:
         """(k, n, C) member scores of n <= max_batch images — ONE
         dispatch at the bucket shape, padded rows already sliced off."""
-        padded, n = self.ladder.pad_block(np.asarray(x, np.float32))
-        s = self._fn(self._members.cnn_params, self._members.beta,
-                     jnp.asarray(padded))
-        return np.asarray(s)[:, :n]
+        with TraceAnnotation(scopes.SERVE_SCORE):
+            with TraceAnnotation(scopes.SERVE_DISPATCH):
+                padded, n = self.ladder.pad_block(np.asarray(x, np.float32))
+                s = self._fn(self._members.cnn_params, self._members.beta,
+                             jnp.asarray(padded))
+            with TraceAnnotation(scopes.SERVE_FETCH):
+                return np.asarray(s)[:, :n]
 
     def predict_block(self, x, combine: str = "mean") -> np.ndarray:
         """(n,) combined ensemble labels of one batch."""

@@ -31,7 +31,9 @@ from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro import scopes
 from repro.serve.engine import COMBINES, BucketedScorer, combine_block
 
 _SHUTDOWN = object()
@@ -214,18 +216,19 @@ class EnsembleServer:
                 break
             batch = [req]
             deadline = req.t_submit + max_wait
-            while len(batch) < self.config.max_batch:
-                timeout = deadline - time.monotonic()
-                if timeout <= 0:
-                    break
-                try:
-                    nxt = self._q.get(timeout=timeout)
-                except queue.Empty:
-                    break
-                if nxt is _SHUTDOWN:
-                    shutdown = True
-                    break
-                batch.append(nxt)
+            with TraceAnnotation(scopes.SERVE_COLLECT):
+                while len(batch) < self.config.max_batch:
+                    timeout = deadline - time.monotonic()
+                    if timeout <= 0:
+                        break
+                    try:
+                        nxt = self._q.get(timeout=timeout)
+                    except queue.Empty:
+                        break
+                    if nxt is _SHUTDOWN:
+                        shutdown = True
+                        break
+                    batch.append(nxt)
             self._flush(batch)
         # drain whatever was submitted before close()
         rest: List[_Request] = []
@@ -241,6 +244,20 @@ class EnsembleServer:
             rest = rest[self.config.max_batch:]
 
     def _flush(self, batch: List[_Request]):
+        """Score one batch and answer its requests, inside a
+        ``repro.serve.flush`` span that carries, while a profiler runs,
+        the batch's size ``n``, its ``bucket`` and ``wait_us``: the sum
+        over the batch of flush start minus submit time."""
+        args = {}
+        if TraceAnnotation.is_enabled():
+            now = time.monotonic()
+            args = dict(n=len(batch),
+                        bucket=self.scorer.ladder.bucket_for(len(batch)),
+                        wait_us=1e6 * sum(now - r.t_submit for r in batch))
+        with TraceAnnotation(scopes.SERVE_FLUSH, **args):
+            self._answer(batch)
+
+    def _answer(self, batch: List[_Request]):
         with self._lock:
             if self._pending_members is not None:
                 self.scorer.swap_members(self._pending_members)

@@ -143,6 +143,38 @@ def test_host_rng_or_clock_fires(tmp_path):
     assert len(found) == 2
 
 
+@pytest.mark.parametrize("src,fires", [
+    ("""\
+        import jax
+        from jax.profiler import TraceAnnotation
+
+        def step(x):
+            with TraceAnnotation("step"):     # opens once, at trace time
+                return x * 2
+
+        @jax.jit
+        def f(xs):
+            return jax.vmap(step)(xs)
+        """, 1),
+    ("""\
+        import jax
+
+        @jax.jit
+        def f(x):
+            with jax.named_scope("step"):     # device scope: fine
+                return x * 2
+
+        def run(x):
+            with jax.profiler.TraceAnnotation("run"):   # host: fine
+                return f(x)
+        """, 0),
+], ids=["fires", "clean"])
+def test_host_span_in_traced(tmp_path, src, fires):
+    found = _lint(tmp_path, src)
+    assert len(found) == fires
+    assert _rules_of(found) == ["host-span-in-traced"] * min(fires, 1)
+
+
 def test_sub_f32_accum_fires(tmp_path):
     found = _lint(tmp_path, """\
         import jax
@@ -368,7 +400,8 @@ def test_cli_list_rules(capsys):
     assert cli_main(["--list-rules"]) == 0
     out = capsys.readouterr().out
     for name in ("np-in-traced", "host-concretization", "host-rng-or-clock",
-                 "sub-f32-accum", "hardcoded-member-seed", "missing-donate",
+                 "host-span-in-traced", "sub-f32-accum",
+                 "hardcoded-member-seed", "missing-donate",
                  "bare-jit-in-serve"):
         assert name in out
 

@@ -13,6 +13,7 @@ one process may load the TPU library at a time, and every pytest-xdist
 worker imports this file. Keep these tests in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,7 +113,16 @@ def test_stacked_epoch_with_sgd_compiles(one_chip, masked):
         solve_each_batch=True, use_pallas=True, masked=masked)
     # 2 conv forwards for the stats, 2 for the loss, 2·2 backward GEMMs
     # (the first conv's dX is dead and may be pruned), 1 elm_stats
-    assert _custom_calls(lowered.compile()) >= 7
+    compiled = lowered.compile()
+    assert _custom_calls(compiled) >= 7
+    # every kernel, the backward GEMMs too, lies under its device scope:
+    # the chip's trace names the op-name path of each op it runs
+    kernels = [ln for ln in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all(re.search(r'op_name="[^"]*/(conv2d|elm_stats)/',
+                                     ln) for ln in kernels)
+    for scope in ("beta_solve", "sgd_update"):
+        assert f"({scope})/" in compiled.as_text()
 
 
 def test_mesh_epoch_compiles_on_four_chips(topo):
