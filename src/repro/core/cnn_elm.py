@@ -26,6 +26,8 @@ This module is the MATH of the Map phase:
   ``vmap``-ed over members, the batch loop rolled into one ``lax.scan``.
   Unequal partitions ride through padding + a per-batch validity mask
   (masked batches contribute zero stats and skip the SGD update).
+  With ``rows`` each step first gathers its batch on the device from the
+  members' rows by row index (``gather_batch``).
 
 HOW that body runs — the epoch/round loop, chunked double-buffered
 host→device pipelining, multi-round syncs, mesh placement/shard_map, and
@@ -182,7 +184,7 @@ def stack_models(models: Sequence[CNNELMModel]) -> StackedMembers:
 
 def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
                        solve_each_batch: bool, use_pallas: bool,
-                       masked: bool):
+                       masked: bool, rows=None):
     """THE stacked scan body: one epoch chunk for ALL members in one
     program. Pure — the executors decide how it is dispatched
     (``_stacked_epoch`` jits it whole-mesh; ``executor._mesh_epoch``
@@ -198,7 +200,12 @@ def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
     U/V/n and leaves the params untouched, so members with fewer real
     batches coast through their padding bit-identically; ``masked=False``
     (all shards equal, no chunk padding) keeps the mask out of the compute
-    graph entirely."""
+    graph entirely.
+
+    ``rows=(xs, ys)``: the members' flat rows and int labels, resident on
+    the device; ``xb`` and ``tb`` are then (nb, k, B) row indices of the
+    batches and of their labels, and each step gathers its batch
+    (``gather_batch``) in the same program."""
     def member_step(params, stats, x, t, m):
         h = cnn.features(cfg, params, x, use_pallas=use_pallas)
         stats = elm.add_stats(stats, elm.batch_stats(
@@ -224,11 +231,39 @@ def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
     def body(carry, batch):
         p, s = carry
         x, t, m = batch
+        if rows is not None:
+            x, t = gather_batch(*rows, x, t, m, cfg)
         return jax.vmap(member_step)(p, s, x, t, m), None
 
     (params_k, stats_k), _ = jax.lax.scan(body, (params_k, stats_k),
                                           (xb, tb, mb))
     return params_k, stats_k
+
+
+def gather_batch(xs, ys, xi, yi, m, cfg):
+    """One scan step's batch for every member, gathered on the device:
+    images x (k, B, H, W, C) and one-hot targets t (k, B, classes), the
+    values of the host build (``partition.padded_stacked_epoch_batches``).
+    ``xs``/``ys`` hold each member's rows, flat (n, H·W·C), and int
+    labels: a tuple of k arrays (members of any size) or one array with a
+    leading member dim (a mesh device's local members). ``xi``/``yi``
+    (k, B) index them (the executors pass the rows of
+    ``partition.padded_epoch_indices`` as both). A padding batch (``m``
+    0) is zeroed, rows and labels, as the host zero-fills it. Flat rows
+    gather as whole rows; on a TPU v5e the (n, H, W) layout took 20x the
+    device time (PERF.md)."""
+    with jax.named_scope(scopes.EPOCH_GATHER):
+        if isinstance(xs, (tuple, list)):
+            def take(rows, idx):
+                return jnp.stack([r[idx[i]] for i, r in enumerate(rows)])
+        else:
+            take = jax.vmap(lambda r, i: r[i])
+        real = (m > 0)[:, None]
+        n = cfg.image_size
+        x = take(xs, xi).reshape(xi.shape + (n, n, cfg.image_channels))
+        x = jnp.where(real[..., None, None, None], x, 0)
+        y = jnp.where(real, take(ys, yi), 0)
+        return x, jax.nn.one_hot(y, cfg.num_classes, dtype=jnp.float32)
 
 
 # the single-device dispatch of the scan body: whole member dim in one jit,

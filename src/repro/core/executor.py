@@ -39,6 +39,17 @@ Telemetry contract (a plain dict, shared with the runner's ``RunResult``):
 chunks, β solves, syncs); ``round_syncs`` the inter-round average+broadcast
 programs.
 
+Epoch build (stacked layouts): where the partitions, as the executor
+places them, fit within half of each device's memory
+(``memory_stats()["bytes_limit"]``; a backend that reports none, such as
+the CPU, counts as fitting), ``execute`` uploads them once and each epoch
+sends only its row indices: each scan step gathers its batch on the
+device, in the same program (``cnn_elm.gather_batch``). Otherwise each
+epoch is built on the host and its batches are uploaded chunk by chunk,
+so data larger than the device still runs with ``chunk_batches``. Both
+feed the scan the same values. Telemetry counts ``device_epoch_builds``
+or ``host_epoch_builds`` per epoch.
+
 Tracing: the programs carry the device scopes of ``repro.scopes``, and
 ``_StackedBase.execute`` opens the host spans of the stacked loop once,
 for both stacked executors.
@@ -82,7 +93,8 @@ from repro.core.cnn_elm import (CNNELMModel, StackedMembers, _bump,
                                 stacked_epoch_scan, train_member,
                                 _stacked_epoch)
 from repro.core.e2lm import psum_stats
-from repro.data.partition import chunk_scan_major, padded_stacked_epoch_batches
+from repro.data.partition import (chunk_scan_major, padded_epoch_indices,
+                                  padded_stacked_epoch_batches)
 from repro.data.synthetic import one_hot
 from repro.distributed import sharding
 from repro.kernels import resolve_use_pallas
@@ -419,16 +431,30 @@ def _val_error_rates(preds_k: np.ndarray, yv) -> np.ndarray:
 _VAL_BATCH = 512       # validation slices score in bounded device batches
 
 
+def _flat_rows(x: np.ndarray) -> np.ndarray:
+    """Images as (n, H·W·C) rows, the layout ``cnn_elm.gather_batch``
+    gathers from (a view of a contiguous array)."""
+    return np.reshape(x, (len(x), -1))
+
+
+def _bytes_limit(device) -> Optional[int]:
+    """The device's memory in bytes; None where the backend reports none
+    (the CPU)."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
 class _StackedBase:
     """Round/epoch/chunk orchestration over the stacked member layout.
 
     Subclasses fix the placement + dispatch details via hooks:
     ``_place_params`` / ``_zero_stats`` (where the carry lives),
-    ``_pad_epoch`` (member-dim padding), ``_put_chunk`` (how batches reach
-    devices), ``_epoch_dispatch`` (plain jit vs shard_map), ``_solve``,
-    ``_snapshot``, ``_averaged`` and ``_sync``. The loop itself — round
-    blocks, per-epoch host array build, double-buffered chunk pipeline,
-    lazy snapshot/averaged closures, telemetry — is written once here.
+    ``_partition_bytes`` / ``_put_partitions`` (the members' rows on the
+    devices), ``_pad_epoch`` (member-dim padding), ``_put_chunk`` (how
+    batches or indices reach devices), ``_epoch_dispatch`` (plain jit vs
+    shard_map), ``_solve``, ``_snapshot``, ``_averaged`` and ``_sync``.
+    The loop itself — round blocks, the per-epoch build (indices for a
+    device gather, or host batches), double-buffered chunk pipeline, lazy
+    snapshot/averaged closures, telemetry — is written once here.
     """
 
     supports_rounds = True
@@ -500,6 +526,14 @@ class _StackedBase:
             with TraceAnnotation(scopes.MAP_PUT):
                 return self._put_chunk(chunk)
 
+        # the members' rows go up once when they fit; each epoch then
+        # sends its indices and the device gathers the batches
+        data = None
+        if self._fits_devices(partitions):
+            with TraceAnnotation(scopes.MAP_PUT):
+                data = self._put_partitions(partitions)
+        built = "host_epoch_builds" if data is None else "device_epoch_builds"
+
         def gather(stats_k):
             with TraceAnnotation(scopes.MAP_GATHER):
                 return self._host_stats(stats_k)
@@ -510,21 +544,25 @@ class _StackedBase:
             stats_k = None      # rng draws were burned above
             for solve_each_batch, lr in passes:
                 with TraceAnnotation(scopes.MAP_EPOCH_BUILD):
-                    xb, tb, mb, chunk = self._epoch_arrays(
+                    arrays, chunk = self._epoch_arrays(
                         partitions, plan.batch_size, rngs, C,
-                        plan.chunk_batches)
-                masked = bool(np.any(mb == 0.0))
+                        plan.chunk_batches, gather=data is not None)
+                _bump(telemetry, key=built)
+                masked = bool(np.any(arrays[-1] == 0.0))
                 stats_k = self._zero_stats(F, C)
-                chunks = chunk_scan_major((xb, tb, mb), chunk)
+                chunks = chunk_scan_major(arrays, chunk)
                 lr_dev = jnp.asarray(lr, jnp.float32)
                 nxt = put(chunks[0])
                 for i in range(len(chunks)):
                     cur, nxt = nxt, (put(chunks[i + 1])
                                      if i + 1 < len(chunks) else None)
+                    if data is not None:
+                        # the batches' and the labels' row indices
+                        cur = (cur[0], cur[0], cur[1])
                     with TraceAnnotation(scopes.MAP_DISPATCH):
                         params_k, stats_k = self._epoch_dispatch(
                             cfg, params_k, stats_k, cur, lr_dev,
-                            solve_each_batch, use_pallas, masked)
+                            solve_each_batch, use_pallas, masked, data)
                     _bump(telemetry)
             last = r == len(round_passes) - 1
             snapshot, averaged, weights = self._round_closures(
@@ -616,27 +654,40 @@ class _StackedBase:
 
         return snapshot, averaged, weights
 
-    # ---- shared host-side epoch building --------------------------------
+    # ---- shared epoch building ------------------------------------------
+
+    def _fits_devices(self, partitions) -> bool:
+        """Whether the partitions, as ``_put_partitions`` places them, fit
+        within half of the memory of each device they go to."""
+        need = self._partition_bytes(partitions)
+        limits = [_bytes_limit(d) for d in self._devices()]
+        return all(need <= lim // 2 for lim in limits if lim is not None)
 
     def _epoch_arrays(self, partitions, batch_size, rngs, num_classes,
-                      chunk_batches):
-        """Scan-major padded epoch arrays on the HOST: xb (nb, k, B, ...),
-        tb (nb, k, B, C) one-hot, mb (nb, k) validity, plus the chunk
-        length (nb itself when not chunking). Each call consumes one
-        permutation per member stream. nb is rounded up to a chunk multiple
-        so every chunk shares one fixed shape (= one jit cache entry)."""
+                      chunk_batches, *, gather: bool):
+        """One epoch's scan-major padded arrays on the HOST, plus the
+        chunk length (nb itself when not chunking). With ``gather``: row
+        indices idx (nb, k, B) and validity mb (nb, k), for the device to
+        gather the batches from; else the batches themselves: xb
+        (nb, k, B, ...), tb (nb, k, B, C) one-hot and mb. Each call
+        consumes one permutation per member stream. nb is rounded up to a
+        chunk multiple so every chunk shares one fixed shape (= one jit
+        cache entry)."""
         nb = max(len(p.x) // batch_size for p in partitions)
         chunk, num_batches = nb, None
         if chunk_batches is not None and 0 < chunk_batches < nb:
             chunk = chunk_batches
             num_batches = -(-nb // chunk) * chunk
-        xs, ys, mk = padded_stacked_epoch_batches(partitions, batch_size,
-                                                  rngs,
-                                                  num_batches=num_batches)
-        tb = one_hot(ys.reshape(-1),
-                     num_classes).reshape(*ys.shape, num_classes)
-        xb, tb, mk = (np.swapaxes(a, 0, 1) for a in (xs, tb, mk))
-        return self._pad_epoch(xb, tb, mk) + (chunk,)
+        if gather:
+            arrays = padded_epoch_indices(partitions, batch_size, rngs,
+                                          num_batches=num_batches)
+        else:
+            xs, ys, mk = padded_stacked_epoch_batches(
+                partitions, batch_size, rngs, num_batches=num_batches)
+            tb = one_hot(ys.reshape(-1),
+                         num_classes).reshape(*ys.shape, num_classes)
+            arrays = tuple(np.swapaxes(a, 0, 1) for a in (xs, tb, mk))
+        return self._pad_epoch(*arrays), chunk
 
     # ---- backend hooks ---------------------------------------------------
 
@@ -660,8 +711,8 @@ class _StackedBase:
             f"the mesh layout would re-pad and re-shard per-member trees "
             f"mid-run; streaming blocks run on 'sequential' or 'stacked'")
 
-    def _pad_epoch(self, xb, tb, mb):
-        return xb, tb, mb
+    def _pad_epoch(self, *arrays):
+        return arrays
 
     def _host_stats(self, stats_k) -> elm.ELMStats:
         """Member-stacked stats on the host (mesh strips the padding)."""
@@ -707,6 +758,21 @@ class StackedExecutor(_StackedBase):
                 stats_k, sharding.member_dim_shardings(stats_k, self.mesh))
         return stats_k
 
+    def _devices(self):
+        return (jax.devices()[:1] if self.mesh is None
+                else list(self.mesh.devices.flat))
+
+    def _partition_bytes(self, partitions) -> int:
+        # every device holds every member's rows (replicated on a mesh)
+        return sum(p.x.nbytes + p.y.nbytes for p in partitions)
+
+    def _put_partitions(self, partitions):
+        # k arrays of flat rows, members of any size: no host copy
+        where = None if self.mesh is None else NamedSharding(self.mesh, P())
+        xs, ys = jax.device_put(([_flat_rows(p.x) for p in partitions],
+                                 [p.y for p in partitions]), where)
+        return tuple(xs), tuple(ys)
+
     def _put_chunk(self, chunk):
         # device_put is async: issuing chunk i+1 while chunk i scans
         # double-buffers the host→device pipeline
@@ -716,10 +782,10 @@ class StackedExecutor(_StackedBase):
             chunk, self.mesh, member_axis=1))
 
     def _epoch_dispatch(self, cfg, params_k, stats_k, cur, lr,
-                        solve_each_batch, use_pallas, masked):
+                        solve_each_batch, use_pallas, masked, rows):
         return _stacked_epoch(cfg, params_k, stats_k, *cur, lr,
                               solve_each_batch=solve_each_batch,
-                              use_pallas=use_pallas, masked=masked)
+                              use_pallas=use_pallas, masked=masked, rows=rows)
 
     def _solve(self, cfg, stats_k):
         return elm.solve_beta(stats_k, cfg.elm_lambda)
@@ -805,24 +871,30 @@ def _replicated_specs(tree):
                                              "use_pallas", "masked"),
                    donate_argnames=("params_k", "stats_k"))
 def _mesh_epoch(cfg, mesh, params_k, stats_k, xb, tb, mb, lr, *,
-                solve_each_batch: bool, use_pallas: bool, masked: bool):
+                solve_each_batch: bool, use_pallas: bool, masked: bool,
+                rows=None):
     """One epoch chunk shard_map-ed over 'pod': each pod scans ONLY its
     local members — the identical ``cnn_elm.stacked_epoch_scan`` body on a
     k/p-member slice, ZERO collectives (members are independent until the
-    Reduce). The donated carry keeps params/stats resident and sharded."""
+    Reduce). The donated carry keeps params/stats resident and sharded.
+    With ``rows`` (member-sharded, resident across epochs) each pod
+    gathers its batches from its OWN members' rows: no row crosses a
+    chip."""
     pspecs = _member_specs(params_k, mesh)
     sspecs = _member_specs(stats_k, mesh)
     bspecs = sharding.stacked_batch_specs((xb, tb, mb), mesh, member_axis=1)
 
-    def local(p, s, x, t, m, lr_):
+    def local(p, s, x, t, m, lr_, r):
         return stacked_epoch_scan(cfg, p, s, x, t, m, lr_,
                                   solve_each_batch=solve_each_batch,
-                                  use_pallas=use_pallas, masked=masked)
+                                  use_pallas=use_pallas, masked=masked,
+                                  rows=r)
 
     return shard_map(local, mesh=mesh,
-                     in_specs=(pspecs, sspecs) + bspecs + (P(),),
+                     in_specs=(pspecs, sspecs) + bspecs
+                     + (P(), _member_specs(rows, mesh)),
                      out_specs=(pspecs, sspecs))(
-        params_k, stats_k, xb, tb, mb, lr)
+        params_k, stats_k, xb, tb, mb, lr, rows)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "lam"))
@@ -1005,6 +1077,7 @@ class MeshExecutor(_StackedBase):
         for a in _member_axes(self.mesh):       # pods, or hosts x pods
             slots *= self.mesh.shape[a]
         self._k_pad = -(-k // slots) * slots    # ceil to a slot multiple
+        self._k_local = self._k_pad // slots    # member slots per device
         spec = sharding.resolve_spec((self._k_pad,), ("member",), self.mesh)
         if spec[0] is None:      # padding guarantees divisibility, so the
             raise ValueError(    # fallback can only mean bad custom rules
@@ -1033,24 +1106,49 @@ class MeshExecutor(_StackedBase):
         return jax.device_put(
             stats_k, sharding.member_dim_shardings(stats_k, self.mesh))
 
-    def _pad_epoch(self, xb, tb, mb):
+    def _pad_epoch(self, *arrays):
         pad = self._k_pad - self._k
-        if pad:
-            z = lambda a: np.concatenate(
-                [a, np.zeros((a.shape[0], pad) + a.shape[2:], a.dtype)],
-                axis=1)
-            xb, tb, mb = z(xb), z(tb), z(mb)
-        return xb, tb, mb
+        if not pad:
+            return arrays
+        return tuple(np.concatenate(
+            [a, np.zeros((a.shape[0], pad) + a.shape[2:], a.dtype)], axis=1)
+            for a in arrays)
+
+    def _devices(self):
+        return list(self.mesh.devices.flat)
+
+    def _partition_bytes(self, partitions) -> int:
+        # each device holds its member slots, padded to the longest member
+        p = partitions[0]
+        row = (p.x.nbytes + p.y.nbytes) // len(p.x)
+        return self._k_local * max(len(p.x) for p in partitions) * row
+
+    def _put_partitions(self, partitions):
+        # one (k_pad, n_max, ...) array per field, zero-padded on the host
+        # and placed member-sharded: each pod receives its own members
+        n = max(len(p.x) for p in partitions)
+
+        def stacked(fields):
+            out = np.zeros((self._k_pad, n) + fields[0].shape[1:],
+                           fields[0].dtype)
+            for i, a in enumerate(fields):
+                out[i, :len(a)] = a
+            return out
+
+        data = (stacked([_flat_rows(p.x) for p in partitions]),
+                stacked([p.y for p in partitions]))
+        return jax.device_put(data,
+                              sharding.member_dim_shardings(data, self.mesh))
 
     def _put_chunk(self, chunk):
         return jax.device_put(chunk, sharding.stacked_batch_shardings(
             chunk, self.mesh, member_axis=1))
 
     def _epoch_dispatch(self, cfg, params_k, stats_k, cur, lr,
-                        solve_each_batch, use_pallas, masked):
+                        solve_each_batch, use_pallas, masked, rows):
         return _mesh_epoch(cfg, self.mesh, params_k, stats_k, *cur, lr,
                            solve_each_batch=solve_each_batch,
-                           use_pallas=use_pallas, masked=masked)
+                           use_pallas=use_pallas, masked=masked, rows=rows)
 
     def _solve(self, cfg, stats_k):
         self._last_stats = stats_k          # for e2lm_global_beta

@@ -334,7 +334,10 @@ class RunResult:
     ``StackedMembers`` on the stacked backend (None on sequential);
     ``rounds`` has one ``RoundRecord`` per averaging round; ``dispatches``
     counts jit round-trips the Map engine issued (the stacked/sequential
-    ratio is exactly the dispatch saving docs/perf.md describes)."""
+    ratio is exactly the dispatch saving docs/perf.md describes);
+    ``device_epoch_builds``/``host_epoch_builds`` count the stacked
+    layouts' epochs gathered on the device from partitions uploaded once,
+    or built on the host because the partitions do not fit."""
     cfg: Any
     members: List[CNNELMModel]
     averaged: CNNELMModel
@@ -346,6 +349,8 @@ class RunResult:
     round_syncs: int = 0     # inter-round average+broadcast dispatches
                              # (rounds - 1 on the stacked backend)
     resumed: bool = False    # True when rebuilt/continued from a checkpoint
+    device_epoch_builds: int = 0
+    host_epoch_builds: int = 0
 
     def ensemble(self, combine: str = "mean") -> "Ensemble":
         """The k members as a batched scoring surface."""
@@ -574,7 +579,11 @@ class AveragingRun:
                          outcome.stacked, records,
                          time.perf_counter() - t0, telemetry["dispatches"],
                          m.backend, telemetry.get("round_syncs", 0),
-                         resumed=resumed)
+                         resumed=resumed,
+                         device_epoch_builds=telemetry.get(
+                             "device_epoch_builds", 0),
+                         host_epoch_builds=telemetry.get(
+                             "host_epoch_builds", 0))
 
     def _resume_elastic(self, partitions, key, ckpt_dir: str, *,
                         round_hook: Optional[Callable],

@@ -31,7 +31,9 @@ instead of replaying e+1 permutations from scratch.
 every member's epoch is padded to the max batch count and a per-batch
 validity mask (1 = real, 0 = padding) rides along; masked batches
 contribute zero to the ELM stats and skip the SGD update (see
-``core.cnn_elm``/``core.elm``).
+``core.cnn_elm``/``core.elm``). ``padded_epoch_indices`` draws the same
+epoch as row indices only, for executors that gather the batches on the
+device from partitions uploaded once.
 """
 from __future__ import annotations
 
@@ -223,6 +225,37 @@ def padded_stacked_epoch_batches(
         ys[i, :y.shape[0]] = y
         mask[i, :x.shape[0]] = 1.0
     return xs, ys, mask
+
+
+def padded_epoch_indices(partitions: Sequence[Partition], batch_size: int,
+                         seeds: Sequence[int],
+                         num_batches: Optional[int] = None,
+                         ) -> Tuple[np.ndarray, np.ndarray]:
+    """The rows of ``padded_stacked_epoch_batches``, scan-major, without
+    the rows: idx (nb, k, B) int32 into each member's own rows, and the
+    mask (nb, k) f32. Each member draws the same permutation and keeps the
+    same floor(n/B)·B prefix, so member i's batch b is
+    ``partitions[i].x[idx[b, i]]``; padding batches point at row 0 (the
+    device zeroes them under the mask)."""
+    perms = []
+    for p, s in zip(partitions, seeds):
+        n = (len(p.x) // batch_size) * batch_size
+        if n == 0:
+            raise ValueError(f"partition of {len(p.x)} rows yields no batch "
+                             f"of {batch_size}")
+        perm = np.random.default_rng(s).permutation(len(p.x))[:n]
+        perms.append(perm.reshape(-1, batch_size))
+    nb = max(len(q) for q in perms)
+    if num_batches is not None:
+        if num_batches < nb:
+            raise ValueError(f"num_batches {num_batches} < max count {nb}")
+        nb = num_batches
+    idx = np.zeros((nb, len(perms), batch_size), np.int32)
+    mask = np.zeros((nb, len(perms)), np.float32)
+    for i, q in enumerate(perms):
+        idx[:len(q), i] = q
+        mask[:len(q), i] = 1.0
+    return idx, mask
 
 
 def chunk_scan_major(arrays: Sequence[np.ndarray], chunk_batches: int

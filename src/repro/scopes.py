@@ -20,11 +20,14 @@ BETA_SOLVE = "beta_solve"    # Cholesky and both triangular solves
 SGD_UPDATE = "sgd_update"    # the ELM loss gradient and the SGD step
 READOUT = "readout"          # Hβ, the ELM readout of a prediction
 REDUCE = "reduce"            # member averages, syncs, gossip mixing
-SCOPES = (CONV2D, ELM_STATS, BETA_SOLVE, SGD_UPDATE, READOUT, REDUCE)
+EPOCH_GATHER = "epoch_gather"   # an epoch's batches gathered on the device
+SCOPES = (CONV2D, ELM_STATS, BETA_SOLVE, SGD_UPDATE, READOUT, REDUCE,
+          EPOCH_GATHER)
 
 # host spans of the stacked Map phase (caller's thread)
-MAP_EPOCH_BUILD = "repro.map.epoch_build"   # host build of the epoch
-MAP_PUT = "repro.map.put"                   # host-to-device of a chunk
+MAP_EPOCH_BUILD = "repro.map.epoch_build"   # host part of an epoch build
+MAP_PUT = "repro.map.put"                   # host-to-device of partitions
+                                            # or of a chunk
 MAP_DISPATCH = "repro.map.dispatch"         # the epoch-chunk program call
 MAP_GATHER = "repro.map.gather"             # the host waits on the stats
 MAP_REDUCE = "repro.reduce"                 # averaged model and syncs

@@ -12,12 +12,12 @@ import numpy as np
 import pytest
 
 from repro.configs.base import get_reduced_config, replace
-from repro.core import cnn_elm, executor
+from repro.core import cnn_elm, executor, faults
 from repro.core.executor import (BACKENDS, ExecutionPlan, MeshExecutor,
                                  SequentialExecutor, StackedExecutor,
                                  make_executor)
 from repro.core.runner import AveragingRun, MapConfig, ReduceConfig
-from repro.data.partition import partition_iid
+from repro.data.partition import partition_iid, partition_unequal
 from repro.data.synthetic import make_extended_mnist
 from repro.models import cnn
 from repro.optim.schedules import dynamic_paper
@@ -158,6 +158,86 @@ def test_sequential_executor_direct(parts):
     ref = cnn_elm.average_models(out.members)
     np.testing.assert_array_equal(np.asarray(fired["avg"].beta),
                                   np.asarray(ref.beta))
+
+
+# ---------------------------------------------------------------------------
+# Epoch build: device gather (partitions fit) vs the host fallback
+# ---------------------------------------------------------------------------
+
+def _host_build(monkeypatch):
+    """Force the host fallback: a device of 2 bytes fits no partition."""
+    monkeypatch.setattr(executor, "_bytes_limit", lambda device: 2)
+
+
+def _run_outputs(res):
+    """Members, β and the averaged model of a run, on the host."""
+    return jax.tree.map(np.asarray, (res.stacked.cnn_params,
+                                     res.stacked.beta,
+                                     res.averaged.cnn_params,
+                                     res.averaged.beta))
+
+
+def _run_case(case, parts, tmp_path):
+    sgd = dict(lr_schedule=dynamic_paper(0.05), batch_size=32)
+    cfg = replace(CFG, elm_lambda=1.0)
+    if case == "stacked_e0":
+        return AveragingRun(CFG, MapConfig(epochs=0, batch_size=32)).run(
+            parts, KEY)
+    if case == "sgd_e2_chunked":
+        return AveragingRun(cfg, MapConfig(epochs=2, chunk_batches=1,
+                                           **sgd)).run(parts, KEY)
+    if case == "unequal_masked":
+        x = np.concatenate([p.x for p in parts])
+        y = np.concatenate([p.y for p in parts])
+        uneq = partition_unequal(x, y, [40, 70, 90], seed=0)
+        return AveragingRun(cfg, MapConfig(epochs=1, chunk_batches=2,
+                                           **sgd)).run(uneq, KEY)
+    # rounds=2, preempted after round 0 and resumed from start_round=1
+    run = AveragingRun(cfg, MapConfig(epochs=2, **sgd), ReduceConfig(rounds=2))
+    crashed, res = faults.run_crash_resume(run, parts, KEY, str(tmp_path),
+                                           unit="round", index=0)
+    assert crashed and res.resumed
+    return res
+
+
+@pytest.mark.parametrize("case", ["stacked_e0", "sgd_e2_chunked",
+                                  "unequal_masked", "rounds2_resume"])
+def test_device_epoch_build_matches_host_build(case, parts, tmp_path,
+                                               monkeypatch):
+    """The epoch gathered on the device feeds the scan the values the
+    host build makes: members, β and the averaged model are bit-identical
+    to the forced host fallback on every stacked path."""
+    dev = _run_case(case, parts, tmp_path / "device")
+    assert dev.device_epoch_builds >= 1 and dev.host_epoch_builds == 0
+    _host_build(monkeypatch)
+    host = _run_case(case, parts, tmp_path / "host")
+    assert host.host_epoch_builds == dev.device_epoch_builds
+    assert host.device_epoch_builds == 0
+    for a, b in zip(jax.tree.leaves(_run_outputs(dev)),
+                    jax.tree.leaves(_run_outputs(host))):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("build", ["host", "device"])
+def test_epoch_build_counters(build, parts, monkeypatch):
+    """One epoch build per epoch, counted where it happened: the host when
+    the partitions do not fit half a device's memory, else the device (a
+    backend reporting no limit, as the CPU, counts as fitting)."""
+    if build == "host":
+        _host_build(monkeypatch)
+    epochs, rounds = 4, 2
+    res = AveragingRun(replace(CFG, elm_lambda=1.0),
+                       MapConfig(epochs=epochs, batch_size=32,
+                                 lr_schedule=dynamic_paper(0.05)),
+                       ReduceConfig(rounds=rounds)).run(parts, KEY)
+    per_round = epochs // rounds
+    built = {"host": res.host_epoch_builds,
+             "device": res.device_epoch_builds}
+    assert built[build] == per_round * rounds
+    assert built["device" if build == "host" else "host"] == 0
+    # the gather rides the epoch's own dispatch: one per epoch, the
+    # rounds - 1 syncs and the final solve
+    assert res.dispatches == epochs + (rounds - 1) + 1
 
 
 # ---------------------------------------------------------------------------

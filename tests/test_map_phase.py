@@ -16,6 +16,7 @@ from repro.core.runner import (AveragingRun, MapConfig, ReduceConfig,
                                evaluate_model, kappa_model)
 from repro.data.partition import (Partition, batches, chunk_scan_major,
                                   epoch_batch_arrays,
+                                  padded_epoch_indices,
                                   padded_stacked_epoch_batches, partition_iid,
                                   partition_unequal, stacked_epoch_batches)
 from repro.data.synthetic import make_extended_mnist
@@ -136,6 +137,31 @@ def test_padded_stacked_epoch_batches(uneq_parts):
     assert xs4.shape[1] == 4 and not mask4[:, 3].any()
     with pytest.raises(ValueError, match="num_batches"):
         padded_stacked_epoch_batches(uneq_parts, 32, [0, 1, 2], num_batches=1)
+
+
+def test_padded_epoch_indices_select_the_padded_batches(uneq_parts):
+    """The index builder draws the padded builder's epoch: member i's
+    batch b is its rows at idx[b, i], scan-major; padding points at row 0
+    under mask 0, and both consume one draw per member stream."""
+    seeds = [1000, 1001, 1002]
+    xs, ys, mask = padded_stacked_epoch_batches(uneq_parts, 32, seeds,
+                                                num_batches=4)
+    rngs = [np.random.default_rng(s) for s in seeds]
+    idx, mb = padded_epoch_indices(uneq_parts, 32, rngs, num_batches=4)
+    assert idx.shape == (4, 3, 32) and idx.dtype == np.int32
+    np.testing.assert_array_equal(mb, mask.T)
+    for i, p in enumerate(uneq_parts):
+        real = mask[i] > 0
+        np.testing.assert_array_equal(p.x[idx[real, i]], xs[i, real])
+        np.testing.assert_array_equal(p.y[idx[real, i]], ys[i, real])
+        assert not idx[~real, i].any()
+    # the live streams moved on by one permutation: epoch 1 follows
+    nxt, _ = padded_epoch_indices(uneq_parts, 32, rngs)
+    ref_x, _ = epoch_batch_arrays(uneq_parts[0], 32, seed=1000, epoch=1)
+    np.testing.assert_array_equal(uneq_parts[0].x[nxt[:len(ref_x), 0]],
+                                  ref_x)
+    with pytest.raises(ValueError, match="num_batches"):
+        padded_epoch_indices(uneq_parts, 32, seeds, num_batches=1)
 
 
 def test_padded_equal_shards_all_ones(parts):

@@ -347,6 +347,34 @@ def test_mesh_unequal_sgd_padded(ds):
                                rtol=1e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("epochs", [0, 2], ids=["e0", "sgd_chunked"])
+def test_mesh_device_epoch_build_matches_host_build(ds, epochs,
+                                                    monkeypatch):
+    """Each pod gathers its own members' batches on the device: with k=3
+    unequal shards on the 8-pod mesh (5 padded member slots), members, β
+    and the averaged model are bit-identical to the forced host build."""
+    cfg = replace(CFG, elm_lambda=1.0)
+    uneq = partition_unequal(ds.x, ds.y, [96, 64, 33], seed=1)   # k=3
+    run = AveragingRun(cfg, MapConfig(
+        epochs=epochs, batch_size=32, backend="mesh", mesh=_mesh(8),
+        chunk_batches=2 if epochs else None,
+        lr_schedule=dynamic_paper(0.05) if epochs else None))
+
+    def outputs(res):
+        return jax.tree.leaves(jax.tree.map(np.asarray, (
+            res.stacked.cnn_params, res.stacked.beta,
+            res.averaged.cnn_params, res.averaged.beta)))
+
+    dev = run.run(uneq, KEY)
+    monkeypatch.setattr(executor, "_bytes_limit", lambda device: 2)
+    host = run.run(uneq, KEY)
+    builds = max(epochs, 1)
+    assert (dev.device_epoch_builds, dev.host_epoch_builds) == (builds, 0)
+    assert (host.device_epoch_builds, host.host_epoch_builds) == (0, builds)
+    for a, b in zip(outputs(dev), outputs(host)):
+        np.testing.assert_array_equal(a, b)
+
+
 # ---------------------------------------------------------------------------
 # Hierarchical two-level Reduce on the ('host','pod') mesh (ISSUE-9):
 # members shard over BOTH axes, every Reduce/sync is an intra-host psum

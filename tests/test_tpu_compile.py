@@ -145,3 +145,54 @@ def test_mesh_epoch_compiles_on_four_chips(topo):
     compiled = lowered.compile()
     assert _custom_calls(compiled) >= 7
     assert "all-reduce" not in compiled.as_text()     # members independent
+
+
+def test_gathered_epoch_compiles_at_the_elm_cell_size(one_chip):
+    """The device-built epoch at the ELM-only cell's shapes (3c-9c, k=4
+    members of 60,000 rows, 300 batches of 200): the gather in front of
+    the scan compiles with the kernels, and the partitions, the gathered
+    epoch and the scan fit well inside one chip's 16 GB."""
+    cfg = get_config("cnn_elm_3c9c")
+    n, nb = 60000, 300
+    f, c = cnn.feature_dim(cfg), cfg.num_classes
+    shapes = jax.eval_shape(lambda: cnn.init_params(cfg,
+                                                    jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: _sds((K_MEMBERS,) + a.shape, one_chip),
+                          shapes)
+    stats = elm.ELMStats(_sds((K_MEMBERS, f, f), one_chip),
+                         _sds((K_MEMBERS, f, c), one_chip),
+                         _sds((K_MEMBERS,), one_chip))
+    xs = tuple(_sds((n, 28, 28), one_chip) for _ in range(K_MEMBERS))
+    ys = tuple(_sds((n,), one_chip, jnp.int32) for _ in range(K_MEMBERS))
+    idx = _sds((nb, K_MEMBERS, B), one_chip, jnp.int32)
+    compiled = cnn_elm._stacked_epoch.lower(
+        cfg, params, stats, idx, idx, _sds((nb, K_MEMBERS), one_chip),
+        _sds((), one_chip), solve_each_batch=False, use_pallas=True,
+        masked=False, rows=(xs, ys)).compile()
+    assert _custom_calls(compiled) >= 3       # two conv GEMMs, elm_stats
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+def test_mesh_gathered_epoch_compiles_on_four_chips(topo):
+    """Each chip gathers its own members' batches inside the shard_map:
+    the partitions are member-sharded, so no collective moves a row."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("pod",),
+                axis_types=(AxisType.Auto,))
+    pod = NamedSharding(mesh, P("pod"))
+    per_batch = NamedSharding(mesh, P(None, "pod"))
+    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), pod),
+                         _sds((K_MEMBERS, F, C), pod),
+                         _sds((K_MEMBERS,), pod))
+    idx = _sds((NB, K_MEMBERS, B), per_batch, jnp.int32)
+    rows = (_sds((K_MEMBERS, 1000, 28, 28), pod),
+            _sds((K_MEMBERS, 1000), pod, jnp.int32))
+    lowered = executor._mesh_epoch.lower(
+        CFG, mesh, _member_params(pod), stats, idx, idx,
+        _sds((NB, K_MEMBERS), per_batch), _sds((), NamedSharding(mesh, P())),
+        solve_each_batch=True, use_pallas=True, masked=True, rows=rows)
+    compiled = lowered.compile()
+    assert _custom_calls(compiled) >= 7
+    text = compiled.as_text()
+    assert not any(c in text for c in ("all-reduce", "all-gather",
+                                       "all-to-all", "collective-permute"))

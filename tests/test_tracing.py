@@ -63,6 +63,32 @@ def test_epoch_program_ops_carry_scopes(sgd):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
 
 
+@pytest.mark.parametrize("sgd", [False, True], ids=["elm_only", "sgd"])
+def test_gathered_epoch_ops_carry_scopes(sgd):
+    """The device-built epoch: the gather of the batches sits under its
+    own scope in front of the scan, never under ``conv2d`` (whose device
+    time the conv roofline reads), and the scan's ops keep theirs."""
+    params = cnn.init_params(CFG, jax.random.PRNGKey(0))
+    F, C = cnn.feature_dim(CFG), CFG.num_classes
+    n, nb, B = 40, 2, 4
+    xs = tuple(jnp.zeros((n, CFG.image_size, CFG.image_size))
+               for _ in range(K))
+    ys = tuple(jnp.zeros((n,), jnp.int32) for _ in range(K))
+    idx = jnp.zeros((nb, K, B), jnp.int32)
+    text = cnn_elm._stacked_epoch.lower(
+        CFG, broadcast_member_dim(params, K), elm.zero_stats_stacked(K, F, C),
+        idx, idx, jnp.ones((nb, K)), jnp.float32(0.1), solve_each_batch=sgd,
+        use_pallas=False, masked=True, rows=(xs, ys)).compile().as_text()
+    bad, n_heavy = _unscoped(text)
+    assert n_heavy >= (8 if sgd else 3) and bad == []
+    gathers = re.findall(r' gather\(.*?op_name="([^"]*)"', text)
+    assert gathers and all(
+        scopes.EPOCH_GATHER in p and scopes.CONV2D not in p for p in gathers)
+    for scope in ((scopes.EPOCH_GATHER, scopes.CONV2D, scopes.ELM_STATS)
+                  + ((scopes.BETA_SOLVE, scopes.SGD_UPDATE) if sgd else ())):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+
+
 def test_scorer_ops_carry_scopes():
     params = cnn.init_params(CFG, jax.random.PRNGKey(0))
     members = cnn_elm.StackedMembers(
@@ -123,9 +149,11 @@ def test_profiled_run_and_endpoint_leave_spans(tmp_path):
     names = {s[0] for s in spans}
     assert names == set(scopes.SPANS)
     count = {n: sum(s[0] == n for s in spans) for n in names}
-    # two rounds of one epoch, each built once and put in chunks of 2
+    # two rounds of one epoch, each built once and put in chunks of 2,
+    # after one upload of the partitions (the device gathers the epochs)
     assert count[scopes.MAP_EPOCH_BUILD] == 2
-    assert count[scopes.MAP_PUT] == count[scopes.MAP_DISPATCH] >= 2
+    assert count[scopes.MAP_PUT] == count[scopes.MAP_DISPATCH] + 1
+    assert count[scopes.MAP_DISPATCH] >= 2
     # one inter-round sync and the final averaged model
     assert count[scopes.MAP_REDUCE] == 2
 
