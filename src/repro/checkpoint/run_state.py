@@ -14,7 +14,10 @@ tmp-rename), holding everything the round produced:
 * ``resume``   — on non-final rounds, the post-sync params every member
   was reset to. THE resume point: broadcasting this tree reproduces the
   uninterrupted run's device state bit-for-bit, because the inter-round
-  sync itself broadcasts one identical row to every member slot.
+  sync itself broadcasts one identical row to every member slot;
+* ``record``   — on the final round of a run that took SGD steps, its
+  step record (``cnn_elm.StepRecord``), so that a finished run rebuilt
+  from its checkpoint hands it back too.
 
 Metadata carries the rng/round cursor (``round``, ``epochs_done`` = batch
 permutations consumed per member stream — the runner fast-forwards each
@@ -36,7 +39,7 @@ import numpy as np
 from repro.checkpoint.ckpt import (latest_step, latest_valid_step, list_steps,
                                    restore_checkpoint, save_checkpoint)
 from repro.core import elastic, elm
-from repro.core.cnn_elm import CNNELMModel, StackedMembers
+from repro.core.cnn_elm import CNNELMModel, StackedMembers, StepRecord
 
 ROUND = "round"
 MEMBER = "member"
@@ -89,6 +92,7 @@ class RoundState:
     averaged: CNNELMModel
     resume_params: Optional[dict]     # post-sync CNN params; None on final
     meta: dict
+    step_record: Optional[StepRecord] = None    # final round, after SGD
 
     @property
     def final(self) -> bool:
@@ -97,7 +101,8 @@ class RoundState:
 
 def save_round(ckpt_dir: str, round_idx: int, *, members: StackedMembers,
                stats: elm.ELMStats, averaged: CNNELMModel,
-               resume_params=None, meta: dict) -> str:
+               resume_params=None, step_record: Optional[StepRecord] = None,
+               meta: dict) -> str:
     tree = {
         "members": {"cnn": members.cnn_params, "beta": members.beta},
         "stats": _stats_tree(stats),
@@ -105,6 +110,9 @@ def save_round(ckpt_dir: str, round_idx: int, *, members: StackedMembers,
     }
     if resume_params is not None:
         tree["resume"] = resume_params
+    if step_record is not None:
+        tree["record"] = {"params": step_record.params,
+                          "mask": step_record.mask}
     return save_checkpoint(ckpt_dir, ROUND, round_idx, tree, meta)
 
 
@@ -123,7 +131,10 @@ def restore_round(ckpt_dir: str, round_idx: Optional[int] = None
         averaged=CNNELMModel(tree["averaged"]["cnn"],
                              tree["averaged"]["beta"]),
         resume_params=tree.get("resume"),
-        meta=meta["metadata"])
+        meta=meta["metadata"],
+        step_record=(StepRecord(tree["record"]["params"],
+                                tree["record"]["mask"])
+                     if "record" in tree else None))
 
 
 def latest_round(ckpt_dir: str) -> Optional[int]:

@@ -173,6 +173,18 @@ class StackedMembers:
         return CNNELMModel(avg_cnn, avg_beta)
 
 
+@dataclass
+class StepRecord:
+    """The members' CNN parameters at the start of every SGD step of a
+    run's last epoch, in the order the steps ran: leaves (nb, k, ...)
+    beside ``mask`` (nb, k), False on a padding step (a member with fewer
+    batches than the longest, or the tail of a chunked epoch), where the
+    params pass through unchanged. The params after the last step are the
+    trained members' own."""
+    params: dict
+    mask: np.ndarray
+
+
 def stack_models(models: Sequence[CNNELMModel]) -> StackedMembers:
     """Host-level models -> the stacked member layout (leaves gain a
     leading k dim) so they can ride the batched scoring surface."""
@@ -205,7 +217,12 @@ def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
     ``rows=(xs, ys)``: the members' flat rows and int labels, resident on
     the device; ``xb`` and ``tb`` are then (nb, k, B) row indices of the
     batches and of their labels, and each step gathers its batch
-    (``gather_batch``) in the same program."""
+    (``gather_batch``) in the same program.
+
+    Returns ``(params_k, stats_k)``; with ``solve_each_batch`` also the
+    step record: every member's params at the start of every step, leaves
+    (nb, k, ...), written under the ``step_record`` scope. Where the scan
+    takes no SGD step it carries no record and writes nothing."""
     def member_step(params, stats, x, t, m):
         h = cnn.features(cfg, params, x, use_pallas=use_pallas)
         stats = elm.add_stats(stats, elm.batch_stats(
@@ -229,15 +246,31 @@ def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
         return params, stats
 
     def body(carry, batch):
-        p, s = carry
-        x, t, m = batch
+        p, s, rec = carry
+        x, t, m, j = batch
         if rows is not None:
             x, t = gather_batch(*rows, x, t, m, cfg)
-        return jax.vmap(member_step)(p, s, x, t, m), None
+        if solve_each_batch:
+            # written here, not emitted as the scan's ys: the scan's own
+            # writes of ys carry no scope of the body
+            with jax.named_scope(scopes.STEP_RECORD):
+                rec = jax.tree.map(
+                    lambda r, a: jax.lax.dynamic_update_index_in_dim(
+                        r, a, j, 0), rec, p)
+        p, s = jax.vmap(member_step)(p, s, x, t, m)
+        return (p, s, rec), None
 
-    (params_k, stats_k), _ = jax.lax.scan(body, (params_k, stats_k),
-                                          (xb, tb, mb))
-    return params_k, stats_k
+    nb = mb.shape[0]
+    rec = steps = None
+    if solve_each_batch:
+        # every row is written before it is read; broadcasting the params
+        # keeps their varying mesh axes inside a shard_map
+        rec = jax.tree.map(lambda a: jnp.broadcast_to(a, (nb,) + a.shape),
+                           params_k)
+        steps = jnp.arange(nb)
+    (params_k, stats_k, rec), _ = jax.lax.scan(
+        body, (params_k, stats_k, rec), (xb, tb, mb, steps))
+    return (params_k, stats_k) if rec is None else (params_k, stats_k, rec)
 
 
 def gather_batch(xs, ys, xi, yi, m, cfg):

@@ -88,8 +88,8 @@ from repro.core.averaging import (average_member_dim, broadcast_member_dim,
                                   gossip_member_dim, gossip_ring_mix,
                                   hierarchical_psum_weighted_mean_members,
                                   psum_weighted_mean_members)
-from repro.core.cnn_elm import (CNNELMModel, StackedMembers, _bump,
-                                average_models, stack_models,
+from repro.core.cnn_elm import (CNNELMModel, StackedMembers, StepRecord,
+                                _bump, average_models, stack_models,
                                 stacked_epoch_scan, train_member,
                                 _stacked_epoch)
 from repro.core.e2lm import psum_stats
@@ -197,10 +197,13 @@ class MapOutcome:
     ``StackedMembers`` on the stacked layouts (None on sequential), and
     the final-epoch ``ELMStats`` of every member (host, member-stacked,
     padding stripped) — what β was solved from, for checkpointing and the
-    elastic/E²LM stats merges."""
+    elastic/E²LM stats merges. ``record``: the stacked layouts' step
+    record of the last SGD epoch (None where no SGD step ran, and on
+    sequential)."""
     members: List[CNNELMModel]
     stacked: Optional[StackedMembers]
     stats: Optional[elm.ELMStats] = None
+    record: Optional[StepRecord] = None
 
 
 def make_executor(backend: str, mesh=None) -> "Executor":
@@ -517,6 +520,8 @@ class _StackedBase:
              for e in range(per_round)] for r in range(plan.rounds)]
         sm = None
         stats_k = None
+        record = None       # the last SGD epoch's (record chunks, mask)
+        step_record = None
         ck = plan.checkpoint
         ck_meta = (run_state.run_fingerprint(
             self.name, partitions, seed=plan.seed, epochs=plan.epochs,
@@ -552,6 +557,8 @@ class _StackedBase:
                 stats_k = self._zero_stats(F, C)
                 chunks = chunk_scan_major(arrays, chunk)
                 lr_dev = jnp.asarray(lr, jnp.float32)
+                if solve_each_batch:
+                    record = ([], arrays[-1])
                 nxt = put(chunks[0])
                 for i in range(len(chunks)):
                     cur, nxt = nxt, (put(chunks[i + 1])
@@ -560,15 +567,19 @@ class _StackedBase:
                         # the batches' and the labels' row indices
                         cur = (cur[0], cur[0], cur[1])
                     with TraceAnnotation(scopes.MAP_DISPATCH):
-                        params_k, stats_k = self._epoch_dispatch(
+                        params_k, stats_k, *rec = self._epoch_dispatch(
                             cfg, params_k, stats_k, cur, lr_dev,
                             solve_each_batch, use_pallas, masked, data)
+                    if solve_each_batch:
+                        record[0].extend(rec)
                     _bump(telemetry)
             last = r == len(round_passes) - 1
             snapshot, averaged, weights = self._round_closures(
                 cfg, params_k, stats_k, plan, r, use_pallas, telemetry)
             if last:
                 sm = snapshot()
+                if record is not None:
+                    step_record = self._step_record(*record)
             else:
                 w = weights()
                 with TraceAnnotation(scopes.MAP_REDUCE):
@@ -592,6 +603,7 @@ class _StackedBase:
                     ck.dir, r, members=snapshot(),
                     stats=gather(stats_k), averaged=averaged(),
                     resume_params=resume,
+                    step_record=step_record,
                     meta={**ck_meta, "round": r,
                           "epochs_done": (r + 1) * per_round,
                           "final": last})
@@ -599,7 +611,16 @@ class _StackedBase:
                     ck.after_save("round", r, path)
             if plan.on_round is not None:
                 plan.on_round(r, snapshot, averaged)
-        return MapOutcome(sm.unstack(), sm, gather(stats_k))
+        return MapOutcome(sm.unstack(), sm, gather(stats_k), step_record)
+
+    def _step_record(self, chunks, mb) -> StepRecord:
+        """The epoch's record chunks joined in step order, padded member
+        slots dropped (``_record_params``), beside the steps' mask."""
+        params = jax.tree.map(
+            lambda *c: c[0] if len(c) == 1 else jnp.concatenate(c),
+            *chunks)
+        return StepRecord(self._record_params(params),
+                          np.asarray(mb)[:, :self._k] > 0)
 
     def _round_closures(self, cfg, params_k, stats_k, plan, r, use_pallas,
                         telemetry):
@@ -717,6 +738,11 @@ class _StackedBase:
     def _host_stats(self, stats_k) -> elm.ELMStats:
         """Member-stacked stats on the host (mesh strips the padding)."""
         return elm.ELMStats(*(np.asarray(a) for a in stats_k))
+
+    def _record_params(self, params):
+        """The step record's (nb, k, ...) params as the run hands them
+        back (mesh: gathered off the mesh like the snapshot)."""
+        return params
 
 
 class StackedExecutor(_StackedBase):
@@ -883,6 +909,10 @@ def _mesh_epoch(cfg, mesh, params_k, stats_k, xb, tb, mb, lr, *,
     pspecs = _member_specs(params_k, mesh)
     sspecs = _member_specs(stats_k, mesh)
     bspecs = sharding.stacked_batch_specs((xb, tb, mb), mesh, member_axis=1)
+    # the step record (nb, k, ...) is member-sharded on its second dim
+    out_specs = (pspecs, sspecs) + ((jax.tree.map(
+        lambda s: P(None, *s), pspecs, is_leaf=lambda x: isinstance(x, P)),)
+        if solve_each_batch else ())
 
     def local(p, s, x, t, m, lr_, r):
         return stacked_epoch_scan(cfg, p, s, x, t, m, lr_,
@@ -893,7 +923,7 @@ def _mesh_epoch(cfg, mesh, params_k, stats_k, xb, tb, mb, lr, *,
     return shard_map(local, mesh=mesh,
                      in_specs=(pspecs, sspecs) + bspecs
                      + (P(), _member_specs(rows, mesh)),
-                     out_specs=(pspecs, sspecs))(
+                     out_specs=out_specs)(
         params_k, stats_k, xb, tb, mb, lr, rows)
 
 
@@ -1165,6 +1195,10 @@ class MeshExecutor(_StackedBase):
 
     def _host_stats(self, stats_k) -> elm.ELMStats:
         return elm.ELMStats(*(np.asarray(a)[:self._k] for a in stats_k))
+
+    def _record_params(self, params):
+        return jax.tree.map(
+            lambda a: jnp.asarray(np.asarray(a)[:, :self._k]), params)
 
     def _check_gossip(self):
         if "host" in self.mesh.shape:

@@ -74,7 +74,7 @@ import numpy as np
 from repro.checkpoint import run_state
 from repro.core import elastic, elm, reduce_strategies
 from repro.core.cnn_elm import (CNNELMModel, StackedMembers,  # noqa: F401
-                                stack_models)
+                                StepRecord, stack_models)
 from repro.core.executor import (BACKENDS, CheckpointConfig,  # noqa: F401
                                  ExecutionPlan, make_executor)
 from repro.core.reduce_strategies import (ReduceContext,  # noqa: F401
@@ -337,7 +337,12 @@ class RunResult:
     ratio is exactly the dispatch saving docs/perf.md describes);
     ``device_epoch_builds``/``host_epoch_builds`` count the stacked
     layouts' epochs gathered on the device from partitions uploaded once,
-    or built on the host because the partitions do not fit."""
+    or built on the host because the partitions do not fit.
+    ``step_record`` (``cnn_elm.StepRecord``): on the stacked layouts, every
+    member's params at the start of each step of the last SGD epoch,
+    (nb, k, ...) per leaf, beside the steps' padding mask; None where no
+    SGD step ran (``epochs=0``) and on sequential. The params after the
+    last step are ``stacked``'s."""
     cfg: Any
     members: List[CNNELMModel]
     averaged: CNNELMModel
@@ -351,6 +356,7 @@ class RunResult:
     resumed: bool = False    # True when rebuilt/continued from a checkpoint
     device_epoch_builds: int = 0
     host_epoch_builds: int = 0
+    step_record: Optional[StepRecord] = None
 
     def ensemble(self, combine: str = "mean") -> "Ensemble":
         """The k members as a batched scoring surface."""
@@ -480,7 +486,7 @@ class AveragingRun:
                         0.0, 0, round_hook(state.round, state.averaged)))
                 return RunResult(self.cfg, members, state.averaged, stacked,
                                  records, 0.0, 0, m.backend, 0,
-                                 resumed=True)
+                                 resumed=True, step_record=state.step_record)
             return self._run(
                 partitions, key, round_hook=round_hook,
                 checkpoint=CheckpointConfig(dir=ckpt_dir, every=every),
@@ -583,7 +589,8 @@ class AveragingRun:
                          device_epoch_builds=telemetry.get(
                              "device_epoch_builds", 0),
                          host_epoch_builds=telemetry.get(
-                             "host_epoch_builds", 0))
+                             "host_epoch_builds", 0),
+                         step_record=outcome.record)
 
     def _resume_elastic(self, partitions, key, ckpt_dir: str, *,
                         round_hook: Optional[Callable],

@@ -21,8 +21,9 @@ SGD_UPDATE = "sgd_update"    # the ELM loss gradient and the SGD step
 READOUT = "readout"          # Hβ, the ELM readout of a prediction
 REDUCE = "reduce"            # member averages, syncs, gossip mixing
 EPOCH_GATHER = "epoch_gather"   # an epoch's batches gathered on the device
+STEP_RECORD = "step_record"     # members' params written at each SGD step
 SCOPES = (CONV2D, ELM_STATS, BETA_SOLVE, SGD_UPDATE, READOUT, REDUCE,
-          EPOCH_GATHER)
+          EPOCH_GATHER, STEP_RECORD)
 
 # host spans of the stacked Map phase (caller's thread)
 MAP_EPOCH_BUILD = "repro.map.epoch_build"   # host part of an epoch build

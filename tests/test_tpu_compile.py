@@ -196,3 +196,51 @@ def test_mesh_gathered_epoch_compiles_on_four_chips(topo):
     text = compiled.as_text()
     assert not any(c in text for c in ("all-reduce", "all-gather",
                                        "all-to-all", "collective-permute"))
+
+
+@pytest.mark.parametrize("form", ["stacked", "mesh"])
+def test_sgd_epoch_with_its_step_record_compiles(topo, form):
+    """The SGD cell's epoch (6c-12c, k=4 members of 60,000 rows, 300
+    batches of 200, gathered on the device) with its step record: every
+    member's params at the start of every step, (300, 4, ...) per leaf,
+    written under the ``step_record`` scope; on four chips member-sharded
+    on its second dim, one member a chip."""
+    n, nb = 60000, 300
+    if form == "stacked":
+        place = member = batch = SingleDeviceSharding(topo.devices[0])
+        xs = tuple(_sds((n, 28 * 28), place) for _ in range(K_MEMBERS))
+        ys = tuple(_sds((n,), place, jnp.int32) for _ in range(K_MEMBERS))
+        lower, head = cnn_elm._stacked_epoch.lower, (CFG,)
+    else:
+        mesh = Mesh(np.asarray(topo.devices).reshape(4), ("pod",),
+                    axis_types=(AxisType.Auto,))
+        place = NamedSharding(mesh, P())
+        member = NamedSharding(mesh, P("pod"))
+        batch = NamedSharding(mesh, P(None, "pod"))
+        xs = _sds((K_MEMBERS, n, 28 * 28), member)
+        ys = _sds((K_MEMBERS, n), member, jnp.int32)
+        lower, head = executor._mesh_epoch.lower, (CFG, mesh)
+    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), member),
+                         _sds((K_MEMBERS, F, C), member),
+                         _sds((K_MEMBERS,), member))
+    idx = _sds((nb, K_MEMBERS, B), batch, jnp.int32)
+    lowered = lower(
+        *head, _member_params(member), stats, idx, idx,
+        _sds((nb, K_MEMBERS), batch),
+        _sds((), place), solve_each_batch=True, use_pallas=True,
+        masked=False, rows=(xs, ys))
+    params, _, record = lowered.out_info
+    for p, r in zip(jax.tree.leaves(params), jax.tree.leaves(record)):
+        assert r.shape == (nb,) + p.shape
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert _custom_calls(compiled) >= 7
+    assert re.search(r'op_name="[^"]*/step_record/', text)
+    if form == "mesh":
+        assert not any(c in text for c in ("all-reduce", "all-gather"))
+        assert all(s.spec[:2] == (None, "pod") for s in jax.tree.leaves(
+            compiled.output_shardings[2]))
+    else:
+        # the rows, the scan and a 9.4 MB record: well inside 16 GB
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
