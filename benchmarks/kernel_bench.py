@@ -13,7 +13,7 @@ import numpy as np
 
 from benchmarks.common import emit, save_result, time_call
 from repro.kernels.conv2d import ops as conv_ops
-from repro.kernels.conv2d.kernel import BM, BN, BK
+from repro.kernels.conv2d.kernel import LANES
 from repro.kernels.elm_stats import ops as elm_ops
 from repro.kernels.swa_attention import ops as swa_ops
 
@@ -23,12 +23,13 @@ def main():
     out = {}
 
     # conv2d — the paper's hot spot at its own geometry (28x28 k=5)
-    x = jnp.asarray(rng.normal(size=(256, 28, 28, 1)).astype(np.float32))
+    # in the kernel's (C, H, W, B) layout, a grid step per LANES images
+    x = jnp.asarray(rng.normal(size=(1, 28, 28, 256)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(5, 5, 1, 6)).astype(np.float32))
     us = time_call(lambda a, b: conv_ops.conv2d_valid(a, b), x, w)
-    vmem_kib = (BM * BK + BK * BN + 2 * BM * BN) * 4 / 1024
+    vmem_kib = (1 * 28 * 32 + 6 * 24 * 24) * LANES * 4 / 1024
     emit("conv2d_28x28_k5_b256", us,
-         f"tile={BM}x{BN}x{BK};vmem_working_set_KiB={vmem_kib:.0f}")
+         f"block={LANES}_images;vmem_block_KiB={vmem_kib:.0f}")
     out["conv2d_us"] = us
 
     # fused elm stats vs two separate GEMMs (HBM-reuse argument)

@@ -1,13 +1,25 @@
-"""Pallas TPU kernel: valid convolution as im2col + blocked MXU matmul.
+"""Pallas TPU kernel: valid convolution with the images on the lanes.
 
-TPU adaptation of the paper's conv hot spot (DESIGN.md §8): the GPU
-shared-memory-reuse argument (Scherer et al. 2010) becomes VMEM residency —
-each (bm x bk) patch tile and (bk x bn) kernel tile is loaded into VMEM
-once per grid step and feeds the 128x128 systolic MXU; a f32 VMEM scratch
-accumulates across the K grid dimension.
+The paper's convs are narrow (1 to 12 channels, 5x5 windows, 28 and 12
+pixel maps), so the TPU's 128-lane axis is filled by the batch, not by
+channels: activations are (C, H, W, B), B minor. A grid step takes a
+128-image block whole — every channel and row of it sits in VMEM — and
+each of the kh·kw·Cin window offsets is a slice along H (a major axis)
+and W (sublanes) of that tile: the windows are built in VMEM, never as an
+im2col matrix in HBM. The contraction over (ci, i, j) is f32
+multiply-adds on the VPU, one weight (a scalar from SMEM) times a
+(rows, OW, 128) slab at a time, accumulated in registers.
 
-The im2col patch extraction happens in ops.py (XLA handles gather/reshape
-well); the kernel itself is the blocked GEMM, grid (M/bm, N/bn, K/bk).
+Every image's result is the same sequence of f32 operations on its own
+lane, whatever else the block holds, so a row's features are bit-equal
+across batch sizes (serving's bucket padding relies on it).
+
+The backward stays in this layout (``custom_vjp``): dX is the same
+kernel run on the cotangent padded by kh-1, kw-1 on each side with the
+kernel flipped and its channel axes swapped (the transposed stencil);
+dW multiplies each shifted input slab by the cotangent and reduces over
+positions in the kernel, leaving one partial sum per lane, which the
+caller sums over images.
 """
 from __future__ import annotations
 
@@ -19,88 +31,165 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import f32_precision, out_vma, resolve_interpret
+from repro.kernels import out_vma, resolve_interpret
 
-# MXU-aligned default tiles (multiples of 128 where the operand allows)
-BM, BN, BK = 128, 128, 128
-
-
-def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x, w = x_ref[...], w_ref[...]
-    acc_ref[...] += jnp.dot(x, w, precision=f32_precision(x, w),
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(k == nk - 1)
-    def _write():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+LANES = 128      # images per grid step: one vreg's lane width
+ACC_VREGS = 24   # the accumulator's (8, 128) registers per slab
 
 
-def _pallas_matmul(x, w, tiles, interpret: bool):
-    M, K = x.shape
-    K2, N = w.shape
-    assert K == K2
-    bm, bn, bk = tiles
-    bm, bn, bk = min(bm, max(M, 8)), min(bn, max(N, 8)), min(bk, max(K, 8))
-    Mp, Kp, Np = (-(-M // bm)) * bm, (-(-K // bk)) * bk, (-(-N // bn)) * bn
-    xp = jnp.pad(x, ((0, Mp - M), (0, Kp - K)))
-    wp = jnp.pad(w, ((0, Kp - K), (0, Np - N)))
-    nk = Kp // bk
-    out = pl.pallas_call(
-        functools.partial(_matmul_kernel, nk=nk),
-        grid=(Mp // bm, Np // bn, nk),
+def _row_block(oh: int, ow: int) -> int:
+    """Rows per accumulator slab: the most output rows (a divisor of
+    ``oh``) whose (rows, ow, 128) f32 slab fits ``ACC_VREGS`` registers."""
+    per_row = -(-ow // 8)
+    return max(r for r in range(1, oh + 1)
+               if oh % r == 0 and r * per_row <= max(ACC_VREGS, per_row))
+
+
+def _conv_kernel(w_ref, x_ref, o_ref, *, kh: int, kw: int):
+    """o[co, r, c, :] = Σ_{ci,i,j} w[i, j, ci, co] · x[ci, r+i, c+j, :].
+    w_ref: the HWIO kernel as one (1, kh·kw·Cin·Cout) row in SMEM (2-D,
+    so a vmap's member axis stays a leading block dim); x_ref
+    (Cin, H, W, 128) and o_ref (Cout, OH, OW, 128) in VMEM."""
+    cin = x_ref.shape[0]
+    cout, oh, ow, lanes = o_ref.shape
+    rh = _row_block(oh, ow)
+
+    def out_channel(co, carry):
+        def row_block(r, carry):
+            r0 = pl.multiple_of(r * rh, rh)
+
+            def in_channel(ci, acc):
+                for i in range(kh):
+                    for j in range(kw):
+                        wv = w_ref[0, ((i * kw + j) * cin + ci) * cout + co]
+                        acc = acc + wv * x_ref[ci, pl.ds(r0 + i, rh),
+                                               pl.ds(j, ow), :]
+                return acc
+
+            acc = jax.lax.fori_loop(
+                0, cin, in_channel, jnp.zeros((rh, ow, lanes), jnp.float32))
+            o_ref[co, pl.ds(r0, rh), :, :] = acc.astype(o_ref.dtype)
+            return carry
+
+        return jax.lax.fori_loop(0, oh // rh, row_block, carry)
+
+    jax.lax.fori_loop(0, cout, out_channel, 0)
+
+
+def _weight_grad_kernel(x_ref, g_ref, o_ref, *, kh: int, kw: int):
+    """o[0, ci·Cout + co, i·kw + j, :] = Σ_{r,c} x[ci, r+i, c+j, :] ·
+    g[co, r, c, :]: each lane's share of dW[i, j, ci, co]."""
+    cin = x_ref.shape[0]
+    cout, oh, ow, lanes = g_ref.shape
+    rh = _row_block(oh, ow)
+
+    def pair(p, carry):
+        ci, co = p // cout, p % cout
+        for i in range(kh):
+            for j in range(kw):
+                acc = jnp.zeros((rh, ow, lanes), jnp.float32)
+                for r0 in range(0, oh, rh):
+                    acc = acc + (x_ref[ci, pl.ds(r0 + i, rh), pl.ds(j, ow), :]
+                                 * g_ref[co, pl.ds(r0, rh), :, :])
+                o_ref[0, p, pl.ds(i * kw + j, 1), :] = jnp.sum(
+                    jnp.sum(acc, axis=0), axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, cin * cout, pair, 0)
+
+
+def _lane_blocks(b: int) -> int:
+    assert b % LANES == 0, f"the image axis ({b}) must fill whole lane blocks"
+    return b // LANES
+
+
+def _forward(x, w, interpret: bool):
+    """x (Cin, H, W, B), B a multiple of 128; w (kh, kw, Cin, Cout)."""
+    cin, h, wd, b = x.shape
+    kh, kw, _, cout = w.shape
+    oh, ow = h - kh + 1, wd - kw + 1
+    return pl.pallas_call(
+        functools.partial(_conv_kernel, kh=kh, kw=kw),
+        grid=(_lane_blocks(b),),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((cin, h, wd, LANES), lambda l: (0, 0, 0, l)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype,
+        out_specs=pl.BlockSpec((cout, oh, ow, LANES), lambda l: (0, 0, 0, l)),
+        out_shape=jax.ShapeDtypeStruct((cout, oh, ow, b), x.dtype,
                                        vma=out_vma(x, w)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(xp, wp)
-    return out[:M, :N]
+    )(w.reshape(1, -1).astype(jnp.float32), x)
 
 
-# The backward is two more blocked GEMMs through the same kernel —
-# dX = G·Wᵀ and dW = Xᵀ·G — so SGD through the conv (Alg. 2 lines 13-14)
-# stays on the Pallas path instead of failing in pallas_call's JVP rule.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _matmul(x, w, tiles, interpret):
-    return _pallas_matmul(x, w, tiles, interpret)
+def _weight_grad(x, g, kh: int, kw: int, interpret: bool):
+    """dW (kh, kw, Cin, Cout) of the valid conv: per-lane partial sums
+    from the kernel, summed here over lane blocks and lanes."""
+    cin, h, wd, b = x.shape
+    cout, oh, ow, _ = g.shape
+    nl = _lane_blocks(b)
+    part = pl.pallas_call(
+        functools.partial(_weight_grad_kernel, kh=kh, kw=kw),
+        grid=(nl,),
+        in_specs=[
+            pl.BlockSpec((cin, h, wd, LANES), lambda l: (0, 0, 0, l)),
+            pl.BlockSpec((cout, oh, ow, LANES), lambda l: (0, 0, 0, l)),
+        ],
+        out_specs=pl.BlockSpec((1, cin * cout, kh * kw, LANES),
+                               lambda l: (l, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nl, cin * cout, kh * kw, LANES),
+                                       jnp.float32, vma=out_vma(x, g)),
+        interpret=interpret,
+    )(x, g)
+    dw = part.sum(axis=(0, 3)).reshape(cin, cout, kh, kw)
+    return dw.transpose(2, 3, 0, 1)
 
 
-def _matmul_fwd(x, w, tiles, interpret):
-    return _pallas_matmul(x, w, tiles, interpret), (x, w)
+# The backward runs the same kernels, so SGD through the conv (Alg. 2
+# lines 13-14) stays on the Pallas path in the lane layout.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv(x, w, interpret):
+    return _forward(x, w, interpret)
 
 
-def _matmul_bwd(tiles, interpret, res, g):
+def _conv_fwd(x, w, interpret):
+    return _forward(x, w, interpret), (x, w)
+
+
+def _conv_bwd(interpret, res, g):
     x, w = res
-    dx = _pallas_matmul(g, w.T.astype(g.dtype), tiles, interpret)
-    dw = _pallas_matmul(x.T.astype(g.dtype), g, tiles, interpret)
+    kh, kw = w.shape[:2]
+    gp = jnp.pad(g, ((0, 0), (kh - 1, kh - 1), (kw - 1, kw - 1), (0, 0)))
+    dx = _forward(gp, w[::-1, ::-1].transpose(0, 1, 3, 2).astype(g.dtype),
+                  interpret)
+    dw = _weight_grad(x, g, kh, kw, interpret)
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
-_matmul.defvjp(_matmul_fwd, _matmul_bwd)
+_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def _blocked_matmul(x, w, *, bm: int, bn: int, bk: int, interpret: bool):
-    return _matmul(x, w, (bm, bn, bk), interpret)
+# A trace names a kernel's ops after the innermost jit around it, as it
+# names the elm_stats kernel's: this one's carry ``_conv2d_valid``, the
+# name of the ops-level entry that holds the ``conv2d`` scope.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _conv2d_valid(x, w, *, interpret: bool):
+    b = x.shape[-1]
+    pad = -b % LANES
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, pad)))
+    y = _conv(x, w, interpret)
+    return y[..., :b] if pad else y
 
 
-def blocked_matmul(x, w, *, bm: int = BM, bn: int = BN, bk: int = BK,
-                   interpret: Optional[bool] = None):
-    """(M,K) @ (K,N) -> (M,N), f32 accumulation. Pads to tile multiples.
-    Differentiable: the VJP runs the same kernel (``_matmul_bwd``).
+def conv2d_valid(x, w, *, interpret: Optional[bool] = None):
+    """x: (Cin, H, W, B), w: (kh, kw, Cin, Cout) -> (Cout, OH, OW, B),
+    valid, stride 1, f32. B is padded with zero images to a multiple of
+    128 and cut back; ``cnn.features`` pads once for the whole stack.
+    Differentiable: the VJP runs the same kernels (``_conv_bwd``).
 
-    ``interpret=None`` derives the mode from the backend: compiled on TPU,
-    interpreter elsewhere (``repro.kernels.resolve_interpret``). Resolved
-    outside the jit so the resolved bool is the static cache key."""
-    return _blocked_matmul(x, w, bm=bm, bn=bn, bk=bk,
-                           interpret=resolve_interpret(interpret))
+    ``interpret=None`` derives the mode from the backend: compiled on
+    TPU, interpreter elsewhere (``repro.kernels.resolve_interpret``).
+    Resolved outside the jit so the resolved bool is the static cache
+    key."""
+    return _conv2d_valid(x, w, interpret=resolve_interpret(interpret))

@@ -14,7 +14,7 @@ the device ops' clock. Both cost next to nothing while no profiler runs;
 """
 
 # device scopes
-CONV2D = "conv2d"            # im2col, the conv GEMM and its backward
+CONV2D = "conv2d"            # the lane-dense conv kernel, its dX and dW
 ELM_STATS = "elm_stats"      # U = HᵀH, V = HᵀT
 BETA_SOLVE = "beta_solve"    # Cholesky and both triangular solves
 SGD_UPDATE = "sgd_update"    # the ELM loss gradient and the SGD step

@@ -7,7 +7,6 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.conv2d import ops as conv_ops, ref as conv_ref
-from repro.kernels.conv2d.kernel import blocked_matmul
 from repro.kernels.elm_stats import ops as elm_ops, ref as elm_ref
 from repro.kernels.swa_attention import ops as swa_ops, ref as swa_ref
 
@@ -19,53 +18,60 @@ def _rand(*shape, dtype=np.float32):
 
 
 # ---------------------------------------------------------------------------
-# conv2d
+# conv2d (layout (C, H, W, B): the images on the lanes)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("b,h,w,cin,k,cout", [
-    (1, 8, 8, 1, 3, 4),
-    (2, 28, 28, 1, 5, 6),     # the paper's input geometry
-    (3, 12, 12, 6, 5, 12),    # the paper's second stage
-    (2, 9, 9, 3, 5, 9),
-])
-def test_conv2d_matches_ref(b, h, w, cin, k, cout):
-    x = _rand(b, h, w, cin)
-    wgt = _rand(k, k, cin, cout)
+# every conv of both configs: (H=W, Cin, Cout) — 3c-9c's 1->3 at 28 px and
+# 3->9 at 12 px, 6c-12c's 1->6 and 6->12
+STAGES = [(28, 1, 3), (12, 3, 9), (28, 1, 6), (12, 6, 12)]
+
+
+@pytest.mark.parametrize("b", [1, 32, 200])
+@pytest.mark.parametrize("hw,cin,cout", STAGES)
+def test_conv2d_matches_ref(hw, cin, cout, b):
+    x = _rand(cin, hw, hw, b)
+    wgt = _rand(5, 5, cin, cout)
     out = conv_ops.conv2d_valid(x, wgt, use_pallas=True)
     ref = conv_ref.conv2d_valid_ref(x, wgt)
+    assert out.shape == (cout, hw - 4, hw - 4, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_blocked_matmul_dtypes(dtype):
-    x = _rand(200, 70).astype(dtype)
-    w = _rand(70, 130).astype(dtype)
-    out = blocked_matmul(x, w, interpret=True)
-    ref = (x.astype(jnp.float32) @ w.astype(jnp.float32)).astype(dtype)
-    tol = 1e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), rtol=tol, atol=tol)
+def test_conv2d_other_window_matches_ref():
+    """A 3x3 window on an 8 px map: nothing in the kernel assumes 5x5."""
+    x, wgt = _rand(2, 8, 8, 3), _rand(3, 3, 2, 4)
+    np.testing.assert_allclose(
+        np.asarray(conv_ops.conv2d_valid(x, wgt, use_pallas=True)),
+        np.asarray(conv_ref.conv2d_valid_ref(x, wgt)), rtol=1e-4, atol=1e-4)
 
 
-@settings(max_examples=15, deadline=None)
-@given(m=st.integers(1, 150), k=st.integers(1, 80), n=st.integers(1, 90))
-def test_blocked_matmul_property(m, k, n):
-    rng = np.random.default_rng(m * 1000 + k * 10 + n)
-    x = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
-    w = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
-    out = blocked_matmul(x, w, bm=32, bn=32, bk=32, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(x @ w),
-                               rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("hw,cin,cout", STAGES)
+def test_conv2d_vjp_matches_ref(hw, cin, cout):
+    """dX (the transposed stencil) and dW (reduced over images and
+    positions) of the kernel's custom VJP against XLA's conv gradient."""
+    x, wgt = _rand(cin, hw, hw, 37), _rand(5, 5, cin, cout)
+    g = _rand(cout, hw - 4, hw - 4, 37)
+
+    def grads(use_pallas):
+        return jax.grad(lambda a, w: jnp.vdot(conv_ops.conv2d_valid(
+            a, w, use_pallas=use_pallas), g), argnums=(0, 1))(x, wgt)
+
+    for a, b in zip(grads(True), grads(False)):
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-5,
+                                   atol=1e-5 * np.abs(b).max())
 
 
-def test_features_grad_through_pallas_matches_xla():
-    """jax.grad of the ELM loss through the Pallas conv (custom VJP, two
-    more blocked GEMMs) equals the XLA-conv gradient at the 6c-12c width."""
+@pytest.mark.parametrize("config", ["cnn_elm_3c9c", "cnn_elm_6c12c"])
+def test_features_grad_through_pallas_matches_xla(config):
+    """jax.grad of the ELM loss through the Pallas conv (custom VJP: dX by
+    the same kernel, dW by the weight-gradient kernel) equals the XLA-conv
+    gradient at the config's widths."""
     from repro.configs.base import get_config
     from repro.core import elm
     from repro.models import cnn
-    cfg = get_config("cnn_elm_6c12c")
+    cfg = get_config(config)
     rng = np.random.default_rng(0)
     params = cnn.init_params(cfg, jax.random.PRNGKey(0))
     x = jnp.asarray(rng.random((4, 28, 28)).astype(np.float32))
@@ -87,15 +93,22 @@ def test_features_grad_through_pallas_matches_xla():
                                    atol=1e-5 * np.abs(b).max())
 
 
-def test_im2col_decomposition():
-    """conv == im2col + matmul (the kernel's structural claim)."""
-    x = _rand(2, 10, 10, 3)
-    w = _rand(3, 3, 3, 5)
-    patches = conv_ref.im2col(x, 3, 3)
-    out = (patches @ w.reshape(27, 5)).reshape(2, 8, 8, 5)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(conv_ref.conv2d_valid_ref(x, w)),
-                               rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("b", [32, 200])
+@pytest.mark.parametrize("config", ["cnn_elm_3c9c", "cnn_elm_6c12c"])
+def test_features_row_bit_equal_across_batch_sizes(config, b):
+    """An image's features through the kernel are the same bits scored
+    alone or inside a batch (serving pads batches to bucket sizes)."""
+    from repro.configs.base import get_config
+    from repro.models import cnn
+    cfg = get_config(config)
+    params = cnn.init_params(cfg, jax.random.PRNGKey(1))
+    x = jnp.asarray(np.random.default_rng(b).random((b, 28, 28))
+                    .astype(np.float32))
+    whole = cnn.features(cfg, params, x, use_pallas=True)
+    for i in (0, b - 1):
+        alone = cnn.features(cfg, params, x[i:i + 1], use_pallas=True)
+        np.testing.assert_array_equal(np.asarray(alone[0]),
+                                      np.asarray(whole[i]))
 
 
 # ---------------------------------------------------------------------------

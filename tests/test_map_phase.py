@@ -360,7 +360,7 @@ def test_backend_env_override_applies_per_call(monkeypatch):
     assert "conv_general_dilated" in auto  # CPU auto -> XLA reference
     monkeypatch.setenv("REPRO_USE_PALLAS", "1")
     forced = str(jax.make_jaxpr(lambda: conv_ops.conv2d_valid(x, w))())
-    assert "conv_general_dilated" not in forced  # im2col + Pallas GEMM
+    assert "conv_general_dilated" not in forced  # the Pallas conv kernel
 
 
 def test_eval_backend_is_pluggable():
@@ -378,7 +378,7 @@ def test_eval_backend_is_pluggable():
     forced = runner._scores_stacked.lower(CFG, params_k, beta_k, x,
                                           use_pallas=True).as_text()
     assert "stablehlo.convolution" in ref        # XLA reference path
-    assert "stablehlo.convolution" not in forced  # im2col + Pallas GEMM
+    assert "stablehlo.convolution" not in forced  # the Pallas conv kernel
 
 
 def test_evaluate_kappa_accept_backend(parts):

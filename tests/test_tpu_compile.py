@@ -4,9 +4,10 @@ The TPU compiler is installed with jaxlib and compiles for a topology that
 is described and not attached, so these tests catch what interpret mode
 cannot: Mosaic refusing a block shape, a kernel without a VJP, a
 ``pallas_call`` without ``vma`` inside ``shard_map``. Shapes are the
-published 6c-2s-12c-2s width at B=200. Each compiled program must hold the
-Pallas kernels (``tpu_custom_call``), so a silent fall back to the XLA conv
-fails here.
+published 6c-2s-12c-2s width at B=200 (and the conv kernel at every
+stage of both configs, and at serving's buckets). Each compiled program
+must hold the Pallas kernels (``tpu_custom_call``), so a silent fall back
+to the XLA conv fails here.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every pytest-xdist
@@ -24,16 +25,18 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
 
 from repro.configs.base import get_config
 from repro.core import cnn_elm, elm, executor
-from repro.kernels.conv2d.kernel import blocked_matmul
+from repro.kernels.conv2d import ops as conv_ops
 from repro.kernels.elm_stats.kernel import elm_stats
 from repro.models import cnn
 
 CFG = get_config("cnn_elm_6c12c")
 B, K_MEMBERS, NB = 200, 4, 2
 F, C = cnn.feature_dim(CFG), CFG.num_classes
-# the two conv GEMMs of 6c-12c: conv1 (B·24·24, 5·5·1)@(25, 6) and
-# conv2 (B·8·8, 5·5·6)@(150, 12)
-GEMMS = [(B * 576, 25, 6), (B * 64, 150, 12)]
+# every conv of both configs, (H=W, Cin, Cout, images), in the kernel's
+# (C, H, W, B) layout under the training cells' vmap over k=4 members at
+# B=200, and serving's buckets of 1 and 32 images for 3c-9c
+CONVS = [(28, 1, 3, B), (12, 3, 9, B), (28, 1, 6, B), (12, 6, 12, B),
+         (28, 1, 3, 1), (12, 3, 9, 1), (28, 1, 3, 32), (12, 3, 9, 32)]
 
 
 @pytest.fixture(scope="module")
@@ -80,14 +83,19 @@ def _member_params(sharding):
                         shapes)
 
 
-@pytest.mark.parametrize("m,k,n", GEMMS)
-def test_conv_gemm_forward_and_vjp_compile(one_chip, m, k, n):
-    x, w = _sds((m, k), one_chip), _sds((k, n), one_chip)
-    fwd = jax.jit(blocked_matmul).lower(x, w).compile()
+@pytest.mark.parametrize("hw,cin,cout,b", CONVS)
+def test_conv_forward_and_vjp_compile(one_chip, hw, cin, cout, b):
+    x = _sds((K_MEMBERS, cin, hw, hw, b), one_chip)
+    w = _sds((K_MEMBERS, 5, 5, cin, cout), one_chip)
+
+    def conv(a, k):
+        return conv_ops.conv2d_valid(a, k, use_pallas=True)
+
+    fwd = jax.jit(jax.vmap(conv)).lower(x, w).compile()
     assert _custom_calls(fwd) == 1
-    bwd = jax.jit(jax.grad(lambda a, b: blocked_matmul(a, b).sum(),
-                           argnums=(0, 1))).lower(x, w).compile()
-    assert _custom_calls(bwd) == 2          # dX = G·Wᵀ and dW = Xᵀ·G
+    bwd = jax.jit(jax.vmap(jax.grad(lambda a, k: (conv(a, k) ** 2).sum(),
+                                    argnums=(0, 1)))).lower(x, w).compile()
+    assert _custom_calls(bwd) == 3      # the forward, dX and dW kernels
 
 
 @pytest.mark.parametrize("masked", [False, True])
@@ -111,11 +119,11 @@ def test_stacked_epoch_with_sgd_compiles(one_chip, masked):
         _sds((NB, K_MEMBERS, B, C), one_chip),
         _sds((NB, K_MEMBERS), one_chip), _sds((), one_chip),
         solve_each_batch=True, use_pallas=True, masked=masked)
-    # 2 conv forwards for the stats, 2 for the loss, 2·2 backward GEMMs
-    # (the first conv's dX is dead and may be pruned), 1 elm_stats
+    # 2 conv forwards for the stats, 2 for the loss, the backward's dX of
+    # conv2 and dW of both (conv1's dX is dead and pruned), 1 elm_stats
     compiled = lowered.compile()
     assert _custom_calls(compiled) >= 7
-    # every kernel, the backward GEMMs too, lies under its device scope:
+    # every kernel, the backward ones too, lies under its device scope:
     # the chip's trace names the op-name path of each op it runs
     kernels = [ln for ln in compiled.as_text().splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
@@ -169,7 +177,7 @@ def test_gathered_epoch_compiles_at_the_elm_cell_size(one_chip):
         cfg, params, stats, idx, idx, _sds((nb, K_MEMBERS), one_chip),
         _sds((), one_chip), solve_each_batch=False, use_pallas=True,
         masked=False, rows=(xs, ys)).compile()
-    assert _custom_calls(compiled) >= 3       # two conv GEMMs, elm_stats
+    assert _custom_calls(compiled) >= 3       # two convs, elm_stats
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
 
