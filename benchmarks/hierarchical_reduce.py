@@ -39,7 +39,6 @@ import sys
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from benchmarks.common import emit, save_result, time_call
 from repro.configs.base import get_reduced_config, replace
@@ -134,9 +133,7 @@ def run_hierarchical(k: int = 8, n_per_class: int = 80, epochs: int = 2,
                                "BENCH_hierarchical_reduce.json")) as f:
             return json.load(f)
 
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.analysis.hlo import audit_executor
-    from repro.core import executor
+    from repro.analysis.hlo import audit_executor, lower_stacked_programs
     from repro.launch.hlo_analysis import collective_stats
     from repro.launch.mesh import make_member_mesh
 
@@ -147,7 +144,7 @@ def run_hierarchical(k: int = 8, n_per_class: int = 80, epochs: int = 2,
     lr = dynamic_paper(0.05)
     parts = partition_iid(ds.x, ds.y, k=k, seed=0)
     reduce_cfg = ReduceConfig(rounds=rounds if epochs else 1)
-    F, C = cnn.feature_dim(cfg), cfg.num_classes
+    F = cnn.feature_dim(cfg)
 
     def meshed(hosts, pods):
         return (make_member_mesh(num_pods=pods) if hosts == 1
@@ -156,19 +153,9 @@ def run_hierarchical(k: int = 8, n_per_class: int = 80, epochs: int = 2,
     def sync_reduce_stats(mesh, kk):
         """(sync CollectiveStats, reduce CollectiveStats, k_pad) at
         member count kk on ``mesh`` — read off the compiled HLO."""
-        ex = executor.MeshExecutor(mesh=mesh)
-        ex._begin(cfg, kk)
-        params_k = ex._place_params(cnn.init_params(cfg, KEY))
-        w = ex._weights_dev(None)
-        sync_hlo = executor._mesh_sync.lower(
-            mesh, params_k, w).compile().as_text()
-        beta_k = jax.device_put(
-            jnp.zeros((ex._k_pad, F, C)),
-            NamedSharding(mesh, P(executor._member_axis_entry(mesh))))
-        red_hlo = executor._mesh_reduce.lower(
-            mesh, (params_k, beta_k), w).compile().as_text()
-        return collective_stats(sync_hlo), collective_stats(red_hlo), \
-            ex._k_pad
+        ex, programs = lower_stacked_programs(cfg, "mesh", mesh=mesh, k=kk)
+        return tuple(collective_stats(programs[name].compile().as_text())
+                     for name in ("_sync", "_reduce")) + (ex._k_pad,)
 
     # ---- the gate: parity + collective audit BEFORE anything persists.
     # Parity is gated on a rounds=1 run: with a SINGLE terminal Reduce

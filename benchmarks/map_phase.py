@@ -24,7 +24,7 @@ Four configs, four JSONs under ``experiments/``:
   (``rounds=1``) vs multi-round parallel-SGD averaging (``rounds=r``): the
   wall-clock price of communicating every epochs/r epochs, with per-round
   dispatch telemetry.
-* ``run_mesh``    → ``BENCH_map_phase_mesh.json`` — the MeshExecutor
+* ``run_mesh``    → ``BENCH_map_phase_mesh.json`` — the member-mesh
   scaling sweep: k members shard_map-ed over {1, 2, 4, 8} simulated pods
   (the process re-execs itself under
   ``--xla_force_host_platform_device_count`` when it sees too few
@@ -46,7 +46,6 @@ import sys
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from benchmarks.common import emit, save_result, time_call
 from repro.configs.base import get_reduced_config
@@ -275,7 +274,7 @@ def run_rounds(k: int = 4, n_per_class: int = 40, epochs: int = 4,
 def run_mesh(k: int = 8, n_per_class: int = 80, epochs: int = 2,
              batch_size: int = 32, rounds: int = 2,
              devices=(1, 2, 4, 8), iters: int = 2, out_dir: str = None):
-    """MeshExecutor scaling sweep: the SAME k-member workload over 1, 2, 4
+    """Member-mesh scaling sweep: the SAME k-member workload over 1, 2, 4
     and 8 simulated pods, against the single-program stacked baseline.
 
     When the current process has fewer devices than ``max(devices)`` it
@@ -327,9 +326,8 @@ def run_mesh(k: int = 8, n_per_class: int = 80, epochs: int = 2,
         with open(os.path.join(out_dir, "BENCH_map_phase_mesh.json")) as f:
             return json.load(f)
 
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.analysis.hlo import ContractViolation, check_one_all_reduce
-    from repro.core import executor
+    from repro.analysis.hlo import (ContractViolation, check_one_all_reduce,
+                                    lower_stacked_programs)
     from repro.launch.hlo_analysis import collective_stats
     from repro.launch.mesh import make_member_mesh
 
@@ -380,19 +378,11 @@ def run_mesh(k: int = 8, n_per_class: int = 80, epochs: int = 2,
         })
 
     # the cost model, read off the compiled HLO at the largest mesh
-    mesh = make_member_mesh(num_pods=need)
-    ex = executor.MeshExecutor(mesh=mesh)
-    ex._begin(cfg, k)
-    params_k = ex._place_params(cnn.init_params(cfg, KEY))
-    w = ex._weights_dev(None)
-    sync_hlo = executor._mesh_sync.lower(
-        mesh, params_k, w).compile().as_text()
+    _, programs = lower_stacked_programs(
+        cfg, "mesh", mesh=make_member_mesh(num_pods=need), k=k)
+    sync_hlo = programs["_sync"].compile().as_text()
     sync_cs = collective_stats(sync_hlo)
-    beta_k = jax.device_put(
-        jnp.zeros((ex._k_pad, cnn.feature_dim(cfg), cfg.num_classes)),
-        NamedSharding(mesh, P("pod")))
-    red_hlo = executor._mesh_reduce.lower(
-        mesh, (params_k, beta_k), w).compile().as_text()
+    red_hlo = programs["_reduce"].compile().as_text()
     red_cs = collective_stats(red_hlo)
 
     payload = {

@@ -136,7 +136,9 @@ def run_reduce_strategies(k: int = 8, n_per_class: int = 80,
 
 def _sweep(k, n_per_class, epochs, batch_size, rounds, gossip_rounds,
            alphas, out_dir, audit_executor):
+    from repro.analysis.hlo import lower_stacked_programs
     from repro.core import executor
+    from repro.core.averaging import broadcast_member_dim
     from repro.launch.hlo_analysis import collective_stats
     from repro.launch.mesh import make_member_mesh
     from repro.models import cnn
@@ -232,26 +234,22 @@ def _sweep(k, n_per_class, epochs, batch_size, rounds, gossip_rounds,
     for rep in audit_executor(cfg, "mesh", mesh=mesh, k=k,
                               gossip_rounds=gossip_rounds):
         rep.raise_if_failed()
-    ex = executor.MeshExecutor(mesh=mesh)
-    ex._begin(cfg, k)
-    params_k = ex._place_params(cnn.init_params(cfg, KEY))
-    w = ex._weights_dev(None)
-    gossip_hlo = executor._mesh_gossip_sync.lower(
-        ex.mesh, params_k, w, rounds=gossip_rounds).compile().as_text()
-    g_cs = collective_stats(gossip_hlo)
-    sync_hlo = executor._mesh_sync.lower(
-        ex.mesh, params_k, w).compile().as_text()
-    s_cs = collective_stats(sync_hlo)
+    ex, programs = lower_stacked_programs(cfg, "mesh", mesh=mesh, k=k,
+                                          gossip_rounds=gossip_rounds)
+    g_cs = collective_stats(programs["_gossip"].compile().as_text())
+    s_cs = collective_stats(programs["_sync"].compile().as_text())
 
     # ---- wall-clock: one timed round-sync each way (structure on a
     # shared CPU, not fabric latency)
+    params_k = ex._put(broadcast_member_dim(cnn.init_params(cfg, KEY),
+                                            ex._k_pad))
+    w = ex._weights(None)
     gossip_us = time_call(
-        lambda: executor._mesh_gossip_sync(ex.mesh, params_k, w,
-                                           rounds=gossip_rounds),
+        lambda: ex._call(executor._gossip, params_k, w,
+                         rounds=gossip_rounds, publish=False),
         warmup=1, iters=3)
     psum_us = time_call(
-        lambda: executor._mesh_sync(ex.mesh, params_k, w),
-        warmup=1, iters=3)
+        lambda: ex._call(executor._sync, params_k, w), warmup=1, iters=3)
 
     payload = {
         "k": k,

@@ -22,9 +22,10 @@ One chip (the default):
   requests: none fails or is dropped, and every answer agrees with the
   batched ``Ensemble`` scores.
 
-Four chips (``--four-chips``) run only the scale-out path: the mesh backend
-on the flat ``('pod',)`` mesh and on ``make_member_mesh(hosts=2)``, one
-member per chip, against the stacked backend on device 0. It shows the
+Four chips (``--four-chips``) run only the scale-out path: the stacked
+executor on the flat ``('pod',)`` mesh of four chips and on
+``make_member_mesh(hosts=2)``, one member per chip, against the same
+executor on the one-device member mesh (device 0). It shows the
 members on 4 distinct devices, the sync's all-reduce count (1 flat, 2 on
 ``('host', 'pod')``) from the compiled programs, the ELM-only β equal to
 stacked within the kernel tolerance, and the averaged model after the SGD
@@ -52,7 +53,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs.base import get_config  # noqa: E402
-from repro.core import cnn_elm, elm  # noqa: E402
+from repro.core import elm, executor  # noqa: E402
 from repro.core.averaging import broadcast_member_dim  # noqa: E402
 from repro.core.runner import (AveragingRun, Ensemble, MapConfig,  # noqa: E402
                                ReduceConfig, evaluate_model)
@@ -140,7 +141,7 @@ def epoch_kernel_count(cfg, nb: int) -> int:
     stats_k = jax.eval_shape(lambda: elm.zero_stats_stacked(K, F, C))
     s = jax.ShapeDtypeStruct
     size = cfg.image_size
-    lowered = cnn_elm._stacked_epoch.lower(
+    lowered = executor._stacked_epoch.lower(
         cfg, params_k, stats_k, s((nb, K, BATCH, size, size), jnp.float32),
         s((nb, K, BATCH, C), jnp.float32), s((nb, K), jnp.float32),
         s((), jnp.float32), solve_each_batch=True,
@@ -247,8 +248,8 @@ def one_chip(seed: int):
 
 
 def four_chips(seed: int):
-    """Mesh vs stacked. The ELM-only pass is held to the kernel β
-    tolerance. After the SGD epoch the two agree through their predictions
+    """Four chips vs the one-device member mesh. The ELM-only pass is
+    held to the kernel β tolerance. After the SGD epoch the two agree through their predictions
     only: on the chip the k=1 shard and the k=4 batch compile to different
     summation orders, and 62 steps that each re-solve β from a
     ~1e6-conditioned normal matrix amplify that rounding."""
@@ -283,7 +284,7 @@ def four_chips(seed: int):
                    for r in audit_executor(cfg, "mesh", mesh=mesh, k=K)}
         collectives = {name: next(c.detail for c in reports[name].checks
                                   if "all-reduce" in c.name)
-                       for name in ("mesh/_mesh_sync", "mesh/_mesh_reduce")}
+                       for name in ("mesh/_sync", "mesh/_reduce")}
 
         elm_only = map_run(cfg, parts, key, epochs=0, backend="mesh",
                            mesh=mesh)
@@ -298,8 +299,8 @@ def four_chips(seed: int):
                                     jax.tree.leaves(b.cnn_params))]
         fields = dict(
             devices=len(devices),
-            sync=collectives["mesh/_mesh_sync"],
-            reduce=collectives["mesh/_mesh_reduce"],
+            sync=collectives["mesh/_sync"],
+            reduce=collectives["mesh/_reduce"],
             elm_only_beta_max_abs_diff=beta_diff(elm_only, stacked_elm),
             elm_only_beta_excess=beta_excess(elm_only, stacked_elm),
             averaged_acc=mesh_acc,
@@ -317,8 +318,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--four-chips", action="store_true",
-                    help="run only the mesh backend on 4 chips against the "
-                         "stacked backend on device 0")
+                    help="run only the 4-chip member mesh against the "
+                         "one-device member mesh on device 0")
     args = ap.parse_args(argv)
     require_chip(4 if args.four_chips else 1)
     cache = use_compile_cache()

@@ -4,8 +4,9 @@ The Tier-1 lint reads *source*; this module reads what XLA actually
 compiled and checks the repo's cross-backend averaging contracts on the
 artifact itself, one place instead of per-test string matching:
 
-* **collective count** — the MeshExecutor's Reduce and every inter-round
-  sync lower to EXACTLY ONE all-reduce on the flat 1-D member mesh (the
+* **collective count** — the stacked executor's Reduce and every
+  inter-round sync lower to NONE where one device holds the members,
+  EXACTLY ONE all-reduce on a flat multi-device member mesh (the
   flat-psum contract of ``averaging.psum_weighted_mean_members``) and
   EXACTLY TWO on the hierarchical ``('host', 'pod')`` mesh (intra-host
   then inter-host — ``hierarchical_psum_weighted_mean_members``); the
@@ -244,23 +245,24 @@ def audit_executor(cfg, backend: str, *, mesh=None, k: int = 4,
     * ``"sequential"`` — the host Reduce (``average_models`` /
       ``average_trees``): f32 accumulation on bf16 members, zero
       collectives.
-    * ``"stacked"`` — the fused ``_round_sync`` (f32 accumulation, zero
-      collectives) and the donated ``_stacked_epoch`` (aliases present,
-      zero collectives).
-    * ``"mesh"`` — the ``_mesh_sync`` and ``_mesh_reduce`` collective
-      budget (ONE all-reduce on a flat 1-D member mesh, TWO on the
-      hierarchical ``('host', 'pod')`` mesh) + f32 contracts, and the
-      ``_mesh_epoch`` zero-collective + donation contracts, on a real
-      (or forced-host) device mesh. With ``gossip_rounds=T`` the mesh
-      audit ALSO lowers the decentralized ``_mesh_gossip_sync`` and pins
-      its ring budget: exactly ``2·T`` collective-permutes and zero
-      global all-reduces (``check_gossip_sync``).
+    * ``"stacked"`` / ``"mesh"`` — the stacked executor's programs on
+      its member mesh (``mesh``, else the backend's default: one device
+      for ``"stacked"``, every device for ``"mesh"``): ``_sync`` and
+      ``_reduce`` hold the member mesh's collective budget — none where
+      one device holds the members, ONE all-reduce on a flat multi-device
+      mesh, TWO on the hierarchical ``('host', 'pod')`` mesh — with f32
+      accumulation (lowered on bf16 members); the donated
+      ``_stacked_epoch`` holds zero collectives. With ``gossip_rounds=T``
+      the audit ALSO lowers the gossip sync and pins its ring budget on a
+      multi-device mesh: exactly ``2·T`` collective-permutes and zero
+      global all-reduces (``check_gossip_sync``); on one device the ring
+      is the member dim, with no collective.
+
+    Report names are ``"<backend>/<program>"``.
     """
-    from repro.core import elm, executor
+    from repro.core import executor
     from repro.models import cnn
 
-    key = jax.random.PRNGKey(0) if key is None else key
-    F, C = cnn.feature_dim(cfg), cfg.num_classes
     reports: List[AuditReport] = []
 
     if backend == "sequential":
@@ -268,7 +270,9 @@ def audit_executor(cfg, backend: str, *, mesh=None, k: int = 4,
         # (cnn_params, beta) member trees — lowered on bf16 members so
         # the f32 up-cast must live in the PROGRAM, not the inputs
         from repro.core.averaging import average_trees
-        params = cnn.init_params(cfg, key)
+        F, C = cnn.feature_dim(cfg), cfg.num_classes
+        params = cnn.init_params(cfg, jax.random.PRNGKey(0)
+                                 if key is None else key)
         bf16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
         members = [(bf16, jnp.zeros((F, C), jnp.bfloat16))
                    for _ in range(k)]
@@ -279,82 +283,81 @@ def audit_executor(cfg, backend: str, *, mesh=None, k: int = 4,
         reports.append(rep)
         return reports
 
-    if backend == "stacked":
-        from repro.core.averaging import broadcast_member_dim
-        from repro.core.cnn_elm import _stacked_epoch
-        params = cnn.init_params(cfg, key)
-        bf16_k = broadcast_member_dim(
-            jax.tree.map(lambda a: a.astype(jnp.bfloat16), params), k)
-        lowered = executor._round_sync.lower(bf16_k, None)
-        rep = AuditReport("stacked/_round_sync")
-        rep.checks += [check_accum_dtype(lowered),
-                       check_no_collectives(lowered)]
+    ex, programs = lower_stacked_programs(
+        cfg, backend, mesh=mesh, k=k, batch_size=batch_size,
+        num_batches=num_batches, key=key, gossip_rounds=gossip_rounds)
+    mesh = ex.mesh
+    devices = executor._member_devices(mesh)
+    # the per-sync collective budget is a function of the member-mesh
+    # topology: none on one device, one flat psum on ('pod',), the
+    # staged intra-host → inter-host pair on ('host', 'pod')
+    budget = (check_no_collectives if devices == 1 else
+              check_two_all_reduces if "host" in mesh.shape
+              else check_one_all_reduce)
+    for name, lowered in programs.items():
+        rep = AuditReport(f"{backend}/{name}")
+        if name == "_stacked_epoch":
+            rep.checks += [check_no_collectives(lowered),
+                           check_donation(lowered)]
+        else:
+            ring = name == "_gossip" and devices > 1
+            rep.checks += [check_gossip_sync(lowered, rounds=gossip_rounds)
+                           if ring else budget(lowered),
+                           check_accum_dtype(lowered)]
         reports.append(rep)
+    return reports
 
-        params_k = broadcast_member_dim(params, k)
-        stats_k = elm.zero_stats_stacked(k, F, C)
-        xb, tb, mb = _tiny_inputs(cfg, k, batch_size, num_batches)
-        ep = _stacked_epoch.lower(
-            cfg, params_k, stats_k, jnp.asarray(xb), jnp.asarray(tb),
-            jnp.asarray(mb), jnp.float32(0.0), solve_each_batch=True,
-            use_pallas=False, masked=True)
-        rep = AuditReport("stacked/_stacked_epoch")
-        rep.checks += [check_donation(ep), check_no_collectives(ep)]
-        reports.append(rep)
-        return reports
 
-    if backend == "mesh":
-        ex = executor.MeshExecutor(mesh=mesh)
-        ex._begin(cfg, k)
-        mesh = ex.mesh
-        # the per-sync collective budget is a function of the member-mesh
-        # topology: one flat psum on ('pod',), the staged intra-host →
-        # inter-host pair on ('host', 'pod')
-        check_sync_collectives = (check_two_all_reduces
-                                  if "host" in mesh.shape
-                                  else check_one_all_reduce)
-        params_k = ex._place_params(cnn.init_params(cfg, key))
-        stats_k = ex._zero_stats(F, C)
-        w = ex._weights_dev(None)
+def lower_stacked_programs(cfg, backend: str = "stacked", *, mesh=None,
+                           k: int = 4, batch_size: int = 8,
+                           num_batches: int = 2, key=None,
+                           gossip_rounds: Optional[int] = None):
+    """Lower the stacked executor's programs on its member mesh
+    (``mesh``, else the default of ``backend``, ``"stacked"`` or
+    ``"mesh"``) for k members, padded as a run pads them. Returns the
+    executor and ``{name: Lowered}``: ``_sync`` and ``_reduce`` on bf16
+    members (so an f32 up-cast must live in the program, not the
+    inputs), the donated ``_stacked_epoch`` on tiny batches and, with
+    ``gossip_rounds``, the ``_gossip`` sync."""
+    from repro.core import elm, executor
+    from repro.core.averaging import broadcast_member_dim
+    from repro.models import cnn
 
-        sync = executor._mesh_sync.lower(mesh, params_k, w)
-        rep = AuditReport("mesh/_mesh_sync")
-        rep.checks += [check_sync_collectives(sync),
-                       check_accum_dtype(sync)]
-        reports.append(rep)
+    if backend not in ("stacked", "mesh"):
+        raise ValueError(f"backend must be one of ('sequential', "
+                         f"'stacked', 'mesh'), got {backend!r}")
+    key = jax.random.PRNGKey(0) if key is None else key
+    F, C = cnn.feature_dim(cfg), cfg.num_classes
+    ex = executor.make_executor(backend, mesh=mesh)
+    ex._begin(cfg, k)
+    params = cnn.init_params(cfg, key)
+    bf16_k = ex._put(broadcast_member_dim(
+        jax.tree.map(lambda a: a.astype(jnp.bfloat16), params), ex._k_pad))
+    w = ex._weights(None)
 
-        beta_k = jax.device_put(
-            jnp.zeros((ex._k_pad, F, C)),
-            jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec(
-                    executor._member_axis_entry(mesh))))
-        red = executor._mesh_reduce.lower(mesh, (params_k, beta_k), w)
-        rep = AuditReport("mesh/_mesh_reduce")
-        rep.checks += [check_sync_collectives(red),
-                       check_accum_dtype(red)]
-        reports.append(rep)
+    def lower(program, *args, **kw):
+        with jax.set_mesh(ex.mesh):
+            return program.lower(*args, **kw)
 
-        xb, tb, mb = _tiny_inputs(cfg, ex._k_pad, batch_size, num_batches)
-        cur = ex._put_chunk((xb, tb, mb))
-        ep = executor._mesh_epoch.lower(
-            cfg, mesh, params_k, stats_k, *cur, jnp.float32(0.0),
-            solve_each_batch=True, use_pallas=False, masked=True)
-        rep = AuditReport("mesh/_mesh_epoch")
-        rep.checks += [check_no_collectives(ep), check_donation(ep)]
-        reports.append(rep)
-
-        if gossip_rounds is not None:
-            ex._check_gossip()      # hierarchical meshes have no ring
-            gs = executor._mesh_gossip_sync.lower(mesh, params_k, w,
-                                                  rounds=gossip_rounds)
-            rep = AuditReport("mesh/_mesh_gossip_sync")
-            rep.checks += [check_gossip_sync(gs, rounds=gossip_rounds),
-                           check_accum_dtype(gs)]
-            reports.append(rep)
-        return reports
-
-    raise ValueError(f"backend must be one of ('sequential', 'stacked', "
-                     f"'mesh'), got {backend!r}")
+    beta_k = ex._put(jnp.zeros((ex._k_pad, F, C), jnp.bfloat16))
+    cur = ex._put(_tiny_inputs(cfg, ex._k_pad, batch_size, num_batches),
+                  member_dim=1)
+    programs = {
+        "_sync": lower(executor._sync, bf16_k, w),
+        "_reduce": lower(executor._reduce, (bf16_k, beta_k), w),
+        "_stacked_epoch": lower(
+            executor._stacked_epoch, cfg,
+            ex._put(broadcast_member_dim(params, ex._k_pad)),
+            ex._put(elm.zero_stats_stacked(ex._k_pad, F, C)), *cur,
+            jnp.float32(0.0), solve_each_batch=True, use_pallas=False,
+            masked=True)}
+    if gossip_rounds is not None:
+        if "host" in ex.mesh.shape:
+            raise ValueError("gossip rides the flat 1-D 'pod' ring — the "
+                             "hierarchical ('host', 'pod') mesh has none")
+        programs["_gossip"] = lower(executor._gossip, bf16_k, w,
+                                    rounds=gossip_rounds, publish=False)
+    return ex, programs
 
 
 def audit_average_step(*, mesh=None, weights: Optional[Sequence] = None,

@@ -5,7 +5,7 @@ One ``round-<r>.npz`` per averaging round (atomic via ``ckpt``'s
 tmp-rename), holding everything the round produced:
 
 * ``members``  — the round's pre-sync member snapshot (stacked CNN params
-  + the solved β, padding already stripped on the mesh backend);
+  + the solved β, padding already stripped on a multi-device mesh);
 * ``stats``    — the final-epoch ``ELMStats`` of every member (the exact
   sufficient statistics β was solved from, so a checkpoint can re-solve
   or E²LM-merge without replaying data);

@@ -11,14 +11,16 @@ Five deployment flavours:
                             pmean per leaf.
 * ``psum_weighted_mean_members`` — inside shard_map over the member axis:
                             the whole (weighted) tree mean as ONE collective
-                            (flat psum) — the MeshExecutor's Reduce/sync and
-                            the bit-reference for the hierarchical flavour.
+                            (flat psum) — the bit-reference for the
+                            hierarchical flavour.
 * ``hierarchical_psum_weighted_mean_members`` — the same weighted mean
                             staged over a multi-axis member mesh (e.g.
                             ``('host', 'pod')``): one intra-host partial
                             psum then one inter-host psum, so the sync
                             compiles to exactly TWO collectives regardless
-                            of global fleet size.
+                            of global fleet size; the stacked executor's
+                            Reduce/sync on every member mesh (no psum
+                            where one device holds the members).
 
 Plus the DECENTRALIZED flavour behind ``ReduceConfig(strategy="gossip")``
 (arXiv:1504.00981 — no fusion center, no global collective at all):
@@ -28,7 +30,8 @@ Plus the DECENTRALIZED flavour behind ``ReduceConfig(strategy="gossip")``
 * ``gossip_ring_mix``     — the in-SPMD mixing loop over a named mesh
                             axis: each round is two ``lax.ppermute``
                             neighbor exchanges, zero all-reduces — the
-                            MeshExecutor's gossip sync rides this.
+                            stacked executor's gossip sync rides this on a
+                            multi-device member mesh.
 """
 from __future__ import annotations
 

@@ -30,10 +30,9 @@ This module is the MATH of the Map phase:
   members' rows by row index (``gather_batch``).
 
 HOW that body runs — the epoch/round loop, chunked double-buffered
-host→device pipelining, multi-round syncs, mesh placement/shard_map, and
+host→device pipelining, multi-round syncs, member-mesh placement, and
 telemetry — lives in ``repro.core.executor`` (``SequentialExecutor`` /
-``StackedExecutor`` / ``MeshExecutor``); ``train_members_stacked`` below
-is a thin veneer over ``StackedExecutor`` kept for engine-level callers.
+``StackedExecutor``, whose ``_stacked_epoch`` dispatches this body).
 The supported entry point is ``repro.core.runner``
 (``MapConfig``/``ReduceConfig``/``AveragingRun`` + the batched
 ``Ensemble`` scoring surface — docs/api.md). The pre-runner
@@ -198,10 +197,9 @@ def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
                        solve_each_batch: bool, use_pallas: bool,
                        masked: bool, rows=None):
     """THE stacked scan body: one epoch chunk for ALL members in one
-    program. Pure — the executors decide how it is dispatched
-    (``_stacked_epoch`` jits it whole-mesh; ``executor._mesh_epoch``
-    shard_maps it over the 'pod' axis so each device scans only its local
-    member slice — the body is identical, so equivalence is structural).
+    program. Pure — ``executor._stacked_epoch`` dispatches it over the
+    member mesh, each device scanning only its own members (the whole
+    member dim on one device).
 
     xb: (nb, k, B, H, W[, C]) batches, tb: (nb, k, B, C) one-hot targets,
     mb: (nb, k) per-batch validity (1 = real, 0 = padding) — scan over nb,
@@ -215,9 +213,10 @@ def stacked_epoch_scan(cfg, params_k, stats_k, xb, tb, mb, lr, *,
     graph entirely.
 
     ``rows=(xs, ys)``: the members' flat rows and int labels, resident on
-    the device; ``xb`` and ``tb`` are then (nb, k, B) row indices of the
-    batches and of their labels, and each step gathers its batch
-    (``gather_batch``) in the same program.
+    the device, one array per member (``gather_batch``);
+    ``xb`` and ``tb`` are then (nb, k, B) row indices of the batches and
+    of their labels, and each step gathers its batch in the same
+    program.
 
     Returns ``(params_k, stats_k)``; with ``solve_each_batch`` also the
     step record: every member's params at the start of every step, leaves
@@ -277,69 +276,24 @@ def gather_batch(xs, ys, xi, yi, m, cfg):
     """One scan step's batch for every member, gathered on the device:
     images x (k, B, H, W, C) and one-hot targets t (k, B, classes), the
     values of the host build (``partition.padded_stacked_epoch_batches``).
-    ``xs``/``ys`` hold each member's rows, flat (n, H·W·C), and int
-    labels: a tuple of k arrays (members of any size) or one array with a
-    leading member dim (a mesh device's local members). ``xi``/``yi``
-    (k, B) index them (the executors pass the rows of
+    ``xs``/``ys`` hold each member's rows, flat, and int labels: tuples
+    of k arrays (n, H·W·C) and (n,), members of any size (on a mesh, the
+    device's part of each member slot, ``executor._put_partitions``).
+    ``xi``/``yi`` (k, B) index them (the executor passes the rows of
     ``partition.padded_epoch_indices`` as both). A padding batch (``m``
     0) is zeroed, rows and labels, as the host zero-fills it. Flat rows
     gather as whole rows; on a TPU v5e the (n, H, W) layout took 20x the
     device time (PERF.md)."""
     with jax.named_scope(scopes.EPOCH_GATHER):
-        if isinstance(xs, (tuple, list)):
-            def take(rows, idx):
-                return jnp.stack([r[idx[i]] for i, r in enumerate(rows)])
-        else:
-            take = jax.vmap(lambda r, i: r[i])
+        def take(rows, idx):
+            return jnp.stack([r[idx[i]] for i, r in enumerate(rows)])
+
         real = (m > 0)[:, None]
         n = cfg.image_size
         x = take(xs, xi).reshape(xi.shape + (n, n, cfg.image_channels))
         x = jnp.where(real[..., None, None, None], x, 0)
         y = jnp.where(real, take(ys, yi), 0)
         return x, jax.nn.one_hot(y, cfg.num_classes, dtype=jnp.float32)
-
-
-# the single-device dispatch of the scan body: whole member dim in one jit,
-# carry donated so each chunk updates buffers in place
-_stacked_epoch = functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "solve_each_batch", "use_pallas", "masked"),
-    donate_argnames=("params_k", "stats_k"))(stacked_epoch_scan)
-
-
-def train_members_stacked(cfg, init_params, partitions: Sequence[Partition],
-                          *, epochs: int, lr_schedule, batch_size: int,
-                          seed_base: int = 1000,
-                          use_pallas: Optional[bool] = None,
-                          mesh=None,
-                          chunk_batches: Optional[int] = None,
-                          rounds: int = 1,
-                          round_weights: Optional[Sequence[float]] = None,
-                          on_round=None,
-                          telemetry: Optional[dict] = None) -> StackedMembers:
-    """Engine-level veneer over ``executor.StackedExecutor`` — the
-    orchestration (round loop, chunk pipeline, telemetry) lives there now;
-    this keeps the historical signature for direct engine callers.
-
-    Matches ``train_member(..., seed=seed_base + i)`` per member (same
-    init, same per-epoch batch order, same update sequence) for ANY
-    partition sizes. ``rounds``/``round_weights`` interleave the epochs
-    with (weighted) average+broadcast syncs; ``on_round(r, snapshot)`` is
-    called per round with a lazy cached ``snapshot()`` returning the
-    pre-sync ``StackedMembers``. ``mesh`` places the member dim via
-    ``sharding.member_dim_shardings`` under implicit GSPMD — for the
-    explicit shard_map path use ``executor.MeshExecutor`` (runner backend
-    ``"mesh"``)."""
-    from repro.core.executor import ExecutionPlan, StackedExecutor
-    plan = ExecutionPlan(
-        epochs=epochs, lr_schedule=lr_schedule, batch_size=batch_size,
-        seed=seed_base, use_pallas=use_pallas, chunk_batches=chunk_batches,
-        rounds=rounds, reduce_weights=round_weights,
-        on_round=None if on_round is None else
-        (lambda r, snapshot, averaged: on_round(r, snapshot)),
-        telemetry=telemetry)
-    return StackedExecutor(mesh=mesh).execute(
-        cfg, init_params, partitions, plan).stacked
 
 
 def average_models(models: Sequence[CNNELMModel],

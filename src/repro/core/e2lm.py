@@ -7,11 +7,11 @@ Three reduce flavours, matching how the framework is deployed:
 * ``psum_stats``        — in-SPMD reduce over a mesh axis: every device
                           computes stats of its local rows, one all-reduce
                           yields the global U, V. Exact, one collective.
-                          The mesh Map-phase executor builds its global
-                          readout on this (``executor.MeshExecutor
+                          The stacked Map-phase executor builds its global
+                          readout on this (``executor.StackedExecutor
                           .e2lm_global_beta``: psum the members' final
-                          stats over 'pod', solve once — the no-partition
-                          β straight from the Map phase).
+                          stats over the member mesh, solve once — the
+                          no-partition β straight from the Map phase).
 * ``OSELMState``        — OS-ELM (Liang et al. 2006) sequential/streaming
                           update via Sherman-Morrison-Woodbury, referenced
                           by the paper as the block-sequential alternative.

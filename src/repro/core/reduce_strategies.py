@@ -25,7 +25,7 @@ so the related work's fixes plug in next to the paper's mean:
                        Over Networks", arXiv:1504.00981): a ``combine``
                        override rather than a weight rule — syncs mix
                        neighbor state over a ring (``lax.ppermute`` on
-                       the mesh backend) instead of one global
+                       a multi-device member mesh) instead of one global
                        all-reduce.
 
 A strategy resolves **member weights + combine**: ``weights(ctx)``

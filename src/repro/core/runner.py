@@ -3,11 +3,11 @@ config objects instead of one 8-kwarg entry point.
 
 * ``MapConfig``    — everything the Map phase needs: epochs, lr schedule,
                      batch size, backend (an ``executor`` name:
-                     ``"sequential"`` host loop, ``"stacked"`` vmap+scan
-                     fast path, or ``"mesh"`` — the stacked body
-                     shard_map-ed over a device mesh's 'pod' axis with a
-                     one-all-reduce Reduce), kernel backend, mesh
-                     placement, chunking, and THE member seed rule.
+                     ``"sequential"`` host loop, or ``"stacked"`` /
+                     ``"mesh"`` — the vmap+scan fast path on a member
+                     mesh of the first device / of every device), kernel
+                     backend, member mesh, chunking, and THE member seed
+                     rule.
 * ``ReduceConfig`` — the Reduce strategy (any
                      ``repro.core.reduce_strategies`` registry entry:
                      uniform / shard_weighted / boosted / gossip /
@@ -56,7 +56,7 @@ Fault tolerance (this layer is what makes the run preemptible):
   line 3's shared-init rule applied mid-training) and LEAVE with their
   final weighted contribution kept in every later average — both backed
   by ``repro.core.elastic.ElasticGroup``, re-stacked per round block on
-  the ``sequential`` and ``stacked`` backends.
+  every backend.
 * ``repro.core.faults`` — injectable crashes (after any durable
   checkpoint) and straggler-drop schedules for exercising all of it.
 """
@@ -98,16 +98,18 @@ class MapConfig:
     ``backend`` names an ``executor`` implementation:
     ``"sequential"`` — the faithful host-loop reference
     (``cnn_elm.train_member`` per member, 3 dispatches per batch);
-    ``"stacked"`` — the single-device fast path (all members vmapped into
-    one donated scan per epoch chunk; ``mesh`` optionally hints GSPMD via
-    ``member_dim_shardings``); ``"mesh"`` — the multi-pod path (the same
-    scan body shard_map-ed over ``mesh``'s 'pod' axis, members padded to a
-    pod multiple when k doesn't divide it, β solved pod-sharded, Reduce
-    and every round sync ONE in-mesh all-reduce; ``mesh=None`` builds a
-    1-D ('pod',) mesh over every visible device). ``use_pallas`` forces
-    the kernel backend on ANY path (None = auto policy);
-    ``chunk_batches`` streams epochs as double-buffered chunks on the
-    stacked layouts."""
+    ``"stacked"`` and ``"mesh"`` — the stacked executor: all members
+    vmapped into one donated scan per epoch chunk on a member mesh, each
+    device scanning its own members (padded to a device multiple when k
+    doesn't divide it), β solved where they live, the Reduce and every
+    round sync one program with no collective on one device and ONE
+    all-reduce (TWO on a ('host', 'pod') mesh) across devices. ``mesh``
+    has one meaning: which devices hold the members (a ``'pod'`` axis
+    required); None is the first device for ``"stacked"`` and a 1-D
+    ('pod',) mesh over every visible device for ``"mesh"``.
+    ``use_pallas`` forces the kernel backend on ANY path (None = auto
+    policy); ``chunk_batches`` streams epochs as double-buffered chunks
+    on the stacked executor."""
     epochs: int = 0
     lr_schedule: Optional[Callable[[int], float]] = None
     batch_size: int = 32
@@ -204,8 +206,8 @@ class ReduceConfig:
 
     ``validation`` — a held-out ``Partition`` scored by strategies that
     weigh members by trained quality (``"boosted"``): after each round's
-    Map, every member predicts the slice (backend-native program: host
-    vmap or in-mesh shard_map) and the per-member error rates become the
+    Map, every member predicts the slice (``executor._val_predict`` on
+    the member mesh) and the per-member error rates become the
     averaging weights. Required by exactly those strategies and rejected
     otherwise (a silently ignored slice would misreport what the weights
     were computed from).
@@ -213,10 +215,9 @@ class ReduceConfig:
     ``rounds`` — how many averaging events the run's epochs split into.
     ``rounds=1``: train all epochs, average once (paper-faithful).
     ``rounds=r>1``: epochs split into r contiguous blocks; after every
-    non-final block the members sync to the (weighted) average — stacked
-    layouts only (backend ``"stacked"``: one ``average_member_dim`` +
-    ``broadcast_member_dim`` program; backend ``"mesh"``: one in-mesh
-    all-reduce, params never leave the mesh between rounds).
+    non-final block the members sync to the (weighted) average — the
+    stacked executor only (one program, ``executor._sync``; params never
+    leave the devices between rounds).
 
     ``sync`` — WHEN the averaging events fire. ``"rounds"`` (default) is
     everything above: a fixed count of evenly spaced syncs. ``"drift"``
@@ -648,8 +649,8 @@ class AveragingRun:
         sched = rc.elastic
         # all three backends run elastic rounds: each round block is one
         # re-stacked executor.execute() over the CURRENT members, and the
-        # mesh backend's _begin(cfg, k) re-pads and re-shards the pod
-        # layout per block — ghost members are pad-and-mask invisible, so
+        # stacked executor re-pads its member mesh's slots per block —
+        # ghost members are pad-and-mask invisible, so
         # joiners/leavers only change the padded k and the weight vector
         if m.epochs <= 0:
             raise ValueError("elastic membership needs SGD epochs "

@@ -102,7 +102,7 @@ def member_dim_specs(tree, mesh: Mesh, rules=None):
     """PartitionSpec pytree for member-stacked arrays (leading dim = the
     'member' logical axis, everything else replicated) — the spec-level
     twin of ``member_dim_shardings``, consumed as shard_map in/out_specs
-    by the mesh Map-phase executor."""
+    by ``trainer.make_average_step``."""
     def one(a):
         logical = ("member",) + (None,) * (a.ndim - 1)
         return resolve_spec(a.shape, logical, mesh, rules)
@@ -111,37 +111,14 @@ def member_dim_specs(tree, mesh: Mesh, rules=None):
 
 def member_dim_shardings(tree, mesh: Mesh, rules=None):
     """NamedSharding pytree for member-stacked arrays (leading dim = the
-    'member' logical axis, everything else replicated). This is the placement
-    contract of the stacked Map phase: each pod holds k/|pod| members and the
-    Reduce mean lowers to one all-reduce across pods. Falls back to full
-    replication when 'member' resolves to no mesh axis (e.g. k not divisible
-    by the pod count, or a mesh without a 'pod' axis — the mesh executor
-    instead pads k to a pod multiple so the fallback never fires there)."""
+    'member' logical axis, everything else replicated): each pod holds
+    k/|pod| members and the Reduce mean lowers to one all-reduce across
+    pods. Falls back to full replication when 'member' resolves to no mesh
+    axis (e.g. k not divisible by the pod count, or a mesh without a 'pod'
+    axis — the stacked executor instead pads k to a pod multiple and
+    places its members itself)."""
     return jax.tree.map(lambda s: NamedSharding(mesh, s),
                         member_dim_specs(tree, mesh, rules),
-                        is_leaf=lambda x: isinstance(x, P))
-
-
-def stacked_batch_specs(tree, mesh: Mesh, member_axis: int = 1, rules=None):
-    """PartitionSpec pytree for scan-major stacked BATCH arrays
-    (nb, k, B, ...) — spec-level twin of ``stacked_batch_shardings``."""
-    def one(a):
-        logical = [None] * a.ndim
-        logical[member_axis] = "member"
-        return resolve_spec(a.shape, tuple(logical), mesh, rules)
-    return jax.tree.map(one, tree)
-
-
-def stacked_batch_shardings(tree, mesh: Mesh, member_axis: int = 1,
-                            rules=None):
-    """NamedSharding pytree for scan-major stacked BATCH arrays
-    (nb, k, B, ...): the member dim sits at ``member_axis`` (axis 1 in the
-    stacked Map phase's scan-major layout), everything else replicated. The
-    chunked host→device pipeline uses this so each pod only receives its own
-    members' batches; same replication fallback as
-    ``member_dim_shardings``."""
-    return jax.tree.map(lambda s: NamedSharding(mesh, s),
-                        stacked_batch_specs(tree, mesh, member_axis, rules),
                         is_leaf=lambda x: isinstance(x, P))
 
 

@@ -18,7 +18,8 @@ from __future__ import annotations
 import os
 
 import jax
-from jax.sharding import AxisType
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 DEFAULT_HOST_DEVICES = 512   # 2x16x16 multi-pod dry-run
 
@@ -72,9 +73,10 @@ def make_host_mesh():
 
 def make_member_mesh(num_pods: int | None = None, *,
                      hosts: int | None = None, pods: int | None = None):
-    """The member mesh for the mesh Map-phase executor
-    (``runner.MapConfig(backend="mesh")``): one pod per distributed-
-    averaging member group.
+    """The member mesh of the stacked Map-phase executor
+    (``runner.MapConfig(backend="stacked"|"mesh")``): one pod per
+    distributed-averaging member group; ``make_member_mesh(1)`` is the
+    first device, the ``"stacked"`` default.
 
     Default is the flat 1-D ``('pod',)`` mesh over the first ``num_pods``
     devices (all of them when ``None``) — every Reduce/sync is ONE global
@@ -96,6 +98,10 @@ def make_member_mesh(num_pods: int | None = None, *,
         raise ValueError("make_member_mesh: pods= requires hosts= "
                          "(use num_pods for the flat 1-D mesh)")
     n = len(jax.devices()) if num_pods is None else num_pods
+    if n == 1:
+        # the first device as it is: no topology to lay a ring over
+        return Mesh(np.asarray(jax.devices()[:1]), ("pod",),
+                    axis_types=(AxisType.Auto,))
     return auto_mesh((n,), ("pod",))
 
 

@@ -3,7 +3,7 @@
 Device scopes (``jax.named_scope``) are set inside jitted code only:
 they go into each operation's HLO metadata, and a TPU trace carries them
 in the op-name path (``tf_op``) of every device op, e.g.
-``jit(stacked_epoch_scan)/while/body/.../conv2d/concatenate``. A program
+``jit(_stacked_epoch)/while/body/.../conv2d/concatenate``. A program
 that runs eagerly is its own jit (``jit(cholesky)``) and carries no
 scope.
 

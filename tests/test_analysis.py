@@ -418,7 +418,7 @@ def test_audit_sequential_backend_green():
 def test_audit_stacked_backend_green():
     reports = hlo.audit_executor(CFG, "stacked", k=3)
     assert {r.program for r in reports} == \
-        {"stacked/_round_sync", "stacked/_stacked_epoch"}
+        {"stacked/_sync", "stacked/_reduce", "stacked/_stacked_epoch"}
     for report in reports:
         assert report.ok, str(report)
         report.raise_if_failed()        # and the raising path is a no-op
@@ -447,12 +447,8 @@ def test_audit_hierarchical_mesh_expects_two_allreduces():
         assert report.ok, str(report)
     # a single-psum program must FAIL the two-collective check
     flat = auto_mesh((8,), ("pod",))
-    from repro.core import executor as ex_mod
-    ex = ex_mod.MeshExecutor(mesh=flat)
-    ex._begin(CFG, 3)
-    params_k = ex._place_params(cnn.init_params(CFG, jax.random.PRNGKey(0)))
-    one = ex_mod._mesh_sync.lower(flat, params_k, ex._weights_dev(None))
-    assert not hlo.check_two_all_reduces(one).ok
+    _, programs = hlo.lower_stacked_programs(CFG, "mesh", mesh=flat, k=3)
+    assert not hlo.check_two_all_reduces(programs["_sync"]).ok
 
 
 def test_audit_average_step_plain_green():

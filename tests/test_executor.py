@@ -1,8 +1,8 @@
 """The execution layer (`repro.core.executor`): backend registry and
-selection, MeshExecutor ≡ StackedExecutor on whatever devices exist (the
-degenerate 1-pod mesh on plain CI; the REAL 8-device matrix re-run in a
-subprocess under a forced host device count), the engine veneer's
-backwards-compatible contract, and the REPRO_HOST_DEVICES override."""
+selection, the ``"mesh"`` default ≡ ``"stacked"`` on whatever devices
+exist (one device on plain CI; the REAL 8-device matrix re-run in a
+subprocess under a forced host device count), the dispatch counts of the
+training cells' plans, and the REPRO_HOST_DEVICES override."""
 import os
 import subprocess
 import sys
@@ -13,7 +13,7 @@ import pytest
 
 from repro.configs.base import get_reduced_config, replace
 from repro.core import cnn_elm, executor, faults
-from repro.core.executor import (BACKENDS, ExecutionPlan, MeshExecutor,
+from repro.core.executor import (BACKENDS, ExecutionPlan,
                                  SequentialExecutor, StackedExecutor,
                                  make_executor)
 from repro.core.runner import AveragingRun, MapConfig, ReduceConfig
@@ -40,8 +40,15 @@ def parts():
 def test_registry_and_backend_names():
     assert BACKENDS == ("sequential", "stacked", "mesh")
     assert isinstance(make_executor("sequential"), SequentialExecutor)
-    assert isinstance(make_executor("stacked"), StackedExecutor)
-    assert isinstance(make_executor("mesh"), MeshExecutor)
+    # "stacked" and "mesh" build the one stacked executor; they differ
+    # only in the default member mesh: the first device, or every device
+    stacked, mesh = make_executor("stacked"), make_executor("mesh")
+    assert type(stacked) is type(mesh) is StackedExecutor
+    assert (stacked.name, mesh.name) == ("stacked", "mesh")
+    assert list(stacked.mesh.devices.flat) == jax.devices()[:1]
+    assert list(mesh.mesh.devices.flat) == jax.devices()
+    given = make_executor("stacked", mesh=mesh.mesh)
+    assert given.mesh is mesh.mesh
     with pytest.raises(ValueError, match="backend"):
         make_executor("gspmd")
     # MapConfig validates against the same registry
@@ -50,7 +57,7 @@ def test_registry_and_backend_names():
         MapConfig(backend="vectorized")
     # only sequential lacks sync points
     assert not SequentialExecutor.supports_rounds
-    assert StackedExecutor.supports_rounds and MeshExecutor.supports_rounds
+    assert StackedExecutor.supports_rounds
 
 
 def test_rounds_rejected_on_sequential_only(parts):
@@ -80,10 +87,8 @@ def test_mesh_backend_matches_stacked_elm_only(parts):
     np.testing.assert_allclose(np.asarray(st.averaged.beta),
                                np.asarray(me.averaged.beta),
                                rtol=1e-5, atol=1e-6)
-    # epochs=0 Map telemetry: one scan chunk + one solve, plus the
-    # one-collective Reduce dispatch behind `averaged`
-    assert st.dispatches == 2
-    assert me.dispatches == 3
+    # epochs=0 Map telemetry: one scan chunk + one solve on either name
+    assert st.dispatches == me.dispatches == 2
 
 
 def test_mesh_backend_sgd_and_chunked_bit_identity(parts):
@@ -115,26 +120,46 @@ def test_mesh_backend_ensemble_and_records(parts):
 
 
 # ---------------------------------------------------------------------------
-# The engine veneer keeps its historical contract
+# The training cells' plans dispatch what they always did
 # ---------------------------------------------------------------------------
 
-def test_train_members_stacked_veneer_on_round(parts):
-    """cnn_elm.train_members_stacked still takes on_round(r, snapshot) and
-    round_weights — the executor adapts the wider (r, snapshot, averaged)
-    contract down to it."""
+@pytest.mark.parametrize("epochs", [0, 1], ids=["elm_e0", "sgd_e1"])
+def test_cell_plans_dispatch_counts(epochs):
+    """The elm and SGD cells' plans (k=4, one device, the whole epoch in
+    one chunk, one round): one epoch program and one β solve — the
+    Reduce behind the averaged model is the run's output and not counted
+    — with the rows gathered on the device."""
+    ds = make_extended_mnist(n_per_class=8, seed=1)
+    parts4 = partition_iid(ds.x, ds.y, k=4, seed=0)
+    res = AveragingRun(replace(CFG, elm_lambda=1.0), MapConfig(
+        epochs=epochs, batch_size=10,
+        lr_schedule=dynamic_paper(0.05) if epochs else None)).run(parts4, KEY)
+    assert res.dispatches == 2
+    assert (res.device_epoch_builds, res.host_epoch_builds) == (1, 0)
+    assert res.round_syncs == 0
+    assert (res.step_record is not None) == bool(epochs)
+
+
+def test_stacked_executor_direct(parts):
+    """The stacked executor is drivable without the runner: on_round
+    fires once per round with working closures, the last round's
+    snapshot is what it hands back, and a plan whose epochs do not split
+    into its rounds is refused."""
     cfg = replace(CFG, elm_lambda=1.0)
     seen = {}
-    sm = cnn_elm.train_members_stacked(
-        cfg, cnn.init_params(cfg, KEY), parts, epochs=2,
-        lr_schedule=dynamic_paper(0.05), batch_size=32, rounds=2,
-        on_round=lambda r, snapshot: seen.setdefault(r, snapshot().beta))
-    assert sorted(seen) == [0, 1]
-    np.testing.assert_array_equal(np.asarray(sm.beta),
+    plan = ExecutionPlan(
+        epochs=2, lr_schedule=dynamic_paper(0.05), batch_size=32, rounds=2,
+        on_round=lambda r, snap, avg: seen.setdefault(r, snap().beta))
+    out = StackedExecutor().execute(cfg, cnn.init_params(cfg, KEY), parts,
+                                    plan)
+    assert sorted(seen) == [0, 1] and out.stacked.k == 3
+    np.testing.assert_array_equal(np.asarray(out.stacked.beta),
                                   np.asarray(seen[1]))
     with pytest.raises(ValueError, match="split evenly"):
-        cnn_elm.train_members_stacked(
-            cfg, cnn.init_params(cfg, KEY), parts, epochs=3,
-            lr_schedule=dynamic_paper(0.05), batch_size=32, rounds=2)
+        StackedExecutor().execute(
+            cfg, cnn.init_params(cfg, KEY), parts,
+            ExecutionPlan(epochs=3, lr_schedule=dynamic_paper(0.05),
+                          batch_size=32, rounds=2))
 
 
 def test_sequential_executor_direct(parts):

@@ -4,6 +4,9 @@ padded/masked scan), the per-epoch-reshuffle rng contract, the chunked
 double-buffered scan's bit-identity, the weighted Reduce, the pluggable
 eval backend, and the map-phase benchmark smoke runs."""
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -22,7 +25,6 @@ from repro.data.partition import (Partition, batches, chunk_scan_major,
 from repro.data.synthetic import make_extended_mnist
 from repro.models import cnn
 from repro.optim.schedules import dynamic_paper
-from repro.launch.mesh import auto_mesh
 
 CFG = get_reduced_config("cnn_elm_6c12c")
 KEY = jax.random.PRNGKey(0)
@@ -41,6 +43,11 @@ def _run(cfg, parts, *, epochs, lr_schedule=None, batch_size,
             strategy="shard_weighted" if weight_by_shard else "uniform"),
     ).run(parts, KEY)
     return res.members, res.averaged
+
+
+def _stacked(cfg, parts, **map_kw):
+    """The trained ``StackedMembers`` of a stacked run from KEY's init."""
+    return AveragingRun(cfg, MapConfig(**map_kw)).run(parts, KEY).stacked
 
 
 @pytest.fixture(scope="module")
@@ -225,9 +232,7 @@ def test_sgd_epoch_through_pallas_conv(parts, backend):
 
 
 def test_stacked_members_api(parts):
-    sm = cnn_elm.train_members_stacked(CFG, cnn.init_params(CFG, KEY), parts,
-                                       epochs=0, lr_schedule=None,
-                                       batch_size=32)
+    sm = _stacked(CFG, parts, epochs=0, batch_size=32)
     assert sm.k == len(parts)
     members = sm.unstack()
     assert len(members) == sm.k
@@ -240,18 +245,62 @@ def test_stacked_members_api(parts):
         rtol=1e-6, atol=1e-7)
 
 
-def test_stacked_with_mesh(parts):
-    """member_dim_shardings placement keeps the stacked path equivalent on a
-    1-device 'pod' mesh (degenerate but exercises the SPMD plumbing)."""
-    mesh = auto_mesh((1,), ("pod",))
-    init = cnn.init_params(CFG, KEY)
-    plain = cnn_elm.train_members_stacked(CFG, init, parts, epochs=0,
-                                          lr_schedule=None, batch_size=32)
-    meshed = cnn_elm.train_members_stacked(CFG, init, parts, epochs=0,
-                                           lr_schedule=None, batch_size=32,
-                                           mesh=mesh)
-    np.testing.assert_allclose(np.asarray(plain.beta),
-                               np.asarray(meshed.beta), rtol=1e-6, atol=1e-6)
+# one SGD epoch over unequal shards (3/2/1 batches), k=3 members: on a
+# mesh of four devices one slot is padding
+_PARITY_RUN = """
+import jax, numpy as np
+from repro.configs.base import get_reduced_config, replace
+from repro.core.runner import AveragingRun, MapConfig
+from repro.data.partition import partition_unequal
+from repro.data.synthetic import make_extended_mnist
+from repro.launch.mesh import make_member_mesh
+from repro.optim.schedules import dynamic_paper
+
+def parity_run(backend, devices):
+    ds = make_extended_mnist(n_per_class=20, seed=0)
+    parts = partition_unequal(ds.x, ds.y, [96, 64, 33], seed=1)
+    cfg = replace(get_reduced_config("cnn_elm_6c12c"), elm_lambda=1.0)
+    res = AveragingRun(cfg, MapConfig(
+        epochs=1, lr_schedule=dynamic_paper(0.05), batch_size=32,
+        backend=backend,
+        mesh=None if backend == "sequential" else make_member_mesh(devices),
+    )).run(parts, jax.random.PRNGKey(0))
+    return [np.asarray(a) for m in res.members
+            for a in jax.tree.leaves((m.cnn_params, m.beta))]
+"""
+
+
+@pytest.mark.parametrize("case", ["one_device", "four_devices",
+                                  "sequential"])
+def test_stacked_parity_on_member_meshes(case, tmp_path):
+    """The stacked executor on the one-device member mesh (the single
+    chip's layout), on a forced four-device CPU mesh (its own process:
+    the device count is fixed at start-up) and the sequential reference
+    train the same members from unequal shards: equal β and params, to
+    the repo's SGD tolerance."""
+    scope: dict = {}
+    exec(_PARITY_RUN, scope)
+    ref = scope["parity_run"]("stacked", 1)
+    if case == "four_devices":
+        out = tmp_path / "four.npz"
+        script = (_PARITY_RUN + "\nassert len(jax.devices()) == 4\n"
+                  f"np.savez({str(out)!r}, *parity_run('mesh', 4))\n")
+        root = os.path.join(os.path.dirname(__file__), "..")
+        env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+                   XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") + " --xla_"
+                              "force_host_platform_device_count=4"))
+        run = subprocess.run([sys.executable, "-c", script], env=env,
+                             cwd=root, capture_output=True, text=True,
+                             timeout=600)
+        assert run.returncode == 0, run.stdout + run.stderr
+        with np.load(out) as f:
+            got = [f[f"arr_{i}"] for i in range(len(f.files))]
+    else:
+        got = scope["parity_run"](
+            "stacked" if case == "one_device" else "sequential", 1)
+    assert len(got) == len(ref)
+    for a, b in zip(ref, got):
+        np.testing.assert_allclose(b, a, rtol=1e-4, atol=2e-5)
 
 
 def test_average_models_weighted(parts):
@@ -311,12 +360,10 @@ def test_chunked_scan_bit_identical(uneq_parts, chunk_batches):
     mask padding AND chunk-tail padding interact."""
     cfg = replace(CFG, elm_lambda=1.0)
     lr = dynamic_paper(0.05)
-    init = cnn.init_params(cfg, KEY)
-    mono = cnn_elm.train_members_stacked(cfg, init, uneq_parts, epochs=2,
-                                         lr_schedule=lr, batch_size=32)
-    chk = cnn_elm.train_members_stacked(cfg, init, uneq_parts, epochs=2,
-                                        lr_schedule=lr, batch_size=32,
-                                        chunk_batches=chunk_batches)
+    mono = _stacked(cfg, uneq_parts, epochs=2, lr_schedule=lr,
+                    batch_size=32)
+    chk = _stacked(cfg, uneq_parts, epochs=2, lr_schedule=lr, batch_size=32,
+                   chunk_batches=chunk_batches)
     np.testing.assert_array_equal(np.asarray(mono.beta), np.asarray(chk.beta))
     for la, lb in zip(jax.tree.leaves(mono.cnn_params),
                       jax.tree.leaves(chk.cnn_params)):
@@ -326,12 +373,8 @@ def test_chunked_scan_bit_identical(uneq_parts, chunk_batches):
 def test_chunked_equal_shards_bit_identical(parts):
     """Equal shards + a chunk size that doesn't divide the epoch (4 batches
     into chunks of 3 → one padded tail chunk) still bit-identical."""
-    init = cnn.init_params(CFG, KEY)
-    mono = cnn_elm.train_members_stacked(CFG, init, parts, epochs=0,
-                                         lr_schedule=None, batch_size=32)
-    chk = cnn_elm.train_members_stacked(CFG, init, parts, epochs=0,
-                                        lr_schedule=None, batch_size=32,
-                                        chunk_batches=3)
+    mono = _stacked(CFG, parts, epochs=0, batch_size=32)
+    chk = _stacked(CFG, parts, epochs=0, batch_size=32, chunk_batches=3)
     np.testing.assert_array_equal(np.asarray(mono.beta), np.asarray(chk.beta))
 
 

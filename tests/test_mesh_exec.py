@@ -1,12 +1,13 @@
-"""MeshExecutor on a REAL multi-device mesh (8 simulated host CPUs).
+"""The stacked executor on a REAL multi-device member mesh (8 simulated
+host CPUs).
 
-Runs the ISSUE-4 acceptance matrix: MeshExecutor ≡ StackedExecutor
-numerics (epochs=0 bit-exact, SGD rtol 1e-4) for equal, unequal AND
+Runs the acceptance matrix: the multi-device mesh ≡ the one-device mesh
+(epochs=0 bit-exact, SGD rtol 1e-4) for equal, unequal AND
 padded member counts (mesh larger than k; k not divisible by the pod
 count — the pad-and-mask contract), rounds parity, shard-weighted Reduce
 parity, the one-all-reduce HLO assertion for the Reduce and every sync,
 the pod-sharded β solve, real ``member_dim_shardings`` placements, and
-the E²LM one-collective global readout.
+the E²LM one-collective global readout, and per-member inits.
 
 Needs ≥8 devices: the whole module SKIPS on the plain tier-1 run (1 real
 CPU device) and is executed two ways instead —
@@ -22,6 +23,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_reduced_config, replace
 from repro.core import elm, executor
+from repro.core.averaging import broadcast_member_dim
+from repro.core.cnn_elm import StackedMembers
 from repro.core.e2lm import reduce_stats
 from repro.core.runner import AveragingRun, MapConfig, ReduceConfig
 from repro.data.partition import (epoch_batch_arrays, partition_iid,
@@ -173,13 +176,19 @@ def test_mesh_2d_extra_axes(ds):
 # The one-collective contract (HLO telemetry) + sharded intermediates
 # ---------------------------------------------------------------------------
 
-def _placed(mesh, k, pods):
-    ex = executor.MeshExecutor(mesh=mesh)
+def _placed(mesh, k):
+    ex = executor.StackedExecutor(mesh=mesh, backend="mesh")
     ex._begin(CFG, k)
-    params_k = ex._place_params(cnn.init_params(CFG, KEY))
+    params_k = ex._put(broadcast_member_dim(cnn.init_params(CFG, KEY),
+                                            ex._k_pad))
     F, C = cnn.feature_dim(CFG), CFG.num_classes
-    stats_k = ex._zero_stats(F, C)
+    stats_k = ex._put(elm.zero_stats_stacked(ex._k_pad, F, C))
     return ex, params_k, stats_k
+
+
+def _lower(mesh, program, *args, **kw):
+    with jax.set_mesh(mesh):
+        return program.lower(*args, **kw)
 
 
 def test_sync_and_reduce_lower_to_one_allreduce():
@@ -188,17 +197,17 @@ def test_sync_and_reduce_lower_to_one_allreduce():
     contract), and the epoch scan contains ZERO collectives — all read
     off the compiled artifacts by the ``repro.analysis.hlo`` auditor."""
     mesh = _mesh(8)
-    ex, params_k, stats_k = _placed(mesh, 3, 8)
-    w = ex._weights_dev(None)
+    ex, params_k, stats_k = _placed(mesh, 3)
+    w = ex._weights(None)
 
-    sync = executor._mesh_sync.lower(mesh, params_k, w)
+    sync = _lower(mesh, executor._sync, params_k, w)
     check = check_one_all_reduce(sync)
     assert check.ok, check
 
     beta_k = jax.device_put(
         jnp.zeros((8, cnn.feature_dim(CFG), CFG.num_classes)),
         NamedSharding(mesh, P("pod")))
-    red = executor._mesh_reduce.lower(mesh, (params_k, beta_k), w)
+    red = _lower(mesh, executor._reduce, (params_k, beta_k), w)
     check = check_one_all_reduce(red)
     assert check.ok, check
 
@@ -206,17 +215,17 @@ def test_sync_and_reduce_lower_to_one_allreduce():
     xb = np.zeros((nb, 8, B) + CFG_IMG, np.float32)
     tb = np.zeros((nb, 8, B, CFG.num_classes), np.float32)
     mb = np.zeros((nb, 8), np.float32)
-    cur = ex._put_chunk((xb, tb, mb))
-    ep = executor._mesh_epoch.lower(
-        CFG, mesh, params_k, stats_k, *cur, jnp.float32(0.0),
-        solve_each_batch=True, use_pallas=False, masked=True)
+    cur = ex._put((xb, tb, mb), member_dim=1)
+    ep = _lower(mesh, executor._stacked_epoch, CFG, params_k, stats_k, *cur,
+                jnp.float32(0.0), solve_each_batch=True, use_pallas=False,
+                masked=True)
     for check in (check_no_collectives(ep), check_donation(ep)):
         assert check.ok, check
 
 
 def test_full_mesh_audit_is_green():
     """``audit_executor(..., "mesh")`` — the one-call CI entry point —
-    passes every check on the real MeshExecutor programs."""
+    passes every check on the real stacked executor's programs."""
     mesh = _mesh(8)
     for report in audit_executor(CFG, "mesh", mesh=mesh, k=3):
         assert report.ok, str(report)
@@ -227,24 +236,24 @@ def test_solve_and_params_stay_pod_sharded():
     members) and the placed params shard k_pad/pods members per device;
     only the snapshot leaves the mesh (and strips padding)."""
     mesh = _mesh(4)
-    ex, params_k, stats_k = _placed(mesh, 6, 4)          # k_pad = 8
+    ex, params_k, stats_k = _placed(mesh, 6)             # k_pad = 8
     assert ex._k_pad == 8
     for leaf in jax.tree.leaves(params_k):
         assert leaf.sharding.spec[0] == "pod"
         assert len(leaf.addressable_shards) == 4
         assert leaf.addressable_shards[0].data.shape[0] == 2   # 8 / 4 pods
-    beta_k = executor._mesh_solve(mesh, stats_k, CFG.elm_lambda)
+    beta_k = ex._call(executor._solve, stats_k, CFG.elm_lambda)
     assert beta_k.sharding.spec[0] == "pod"
     assert beta_k.shape[0] == 8
-    sm = ex._snapshot(params_k, beta_k)
+    sm = StackedMembers(*ex._handed_back((params_k, beta_k), member_dim=0))
     assert sm.k == 6                                      # padding stripped
     assert len(jax.tree.leaves(sm.cnn_params)[0].devices()) == 1  # unsharded
 
 
 def test_member_dim_shardings_real_placement():
-    """sharding.member_dim_shardings / stacked_batch_shardings place real
-    shards on the 8-device mesh: member dim split over 'pod', everything
-    else replicated; indivisible member counts replicate (fallback)."""
+    """sharding.member_dim_shardings places real shards on the 8-device
+    mesh: member dim split over 'pod', everything else replicated;
+    indivisible member counts replicate (fallback)."""
     mesh = _mesh(8)
     tree = {"w": jnp.zeros((8, 5, 3)), "b": jnp.zeros((8,))}
     sh = sharding.member_dim_shardings(tree, mesh)
@@ -254,12 +263,6 @@ def test_member_dim_shardings_real_placement():
     # k=6 does not divide 8 pods -> replicated fallback
     sh6 = sharding.member_dim_shardings({"w": jnp.zeros((6, 5))}, mesh)
     assert sh6["w"].spec == P(None, None)
-    # scan-major batches: member dim at axis 1
-    xb = jnp.zeros((4, 8, 16, 5, 5))
-    bsh = sharding.stacked_batch_shardings((xb,), mesh, member_axis=1)
-    assert bsh[0].spec == P(None, "pod", None, None, None)
-    pb = jax.device_put(xb, bsh[0])
-    assert pb.addressable_shards[0].data.shape == (4, 1, 16, 5, 5)
 
 
 def test_e2lm_global_beta_one_psum_of_stats(ds):
@@ -269,7 +272,7 @@ def test_e2lm_global_beta_one_psum_of_stats(ds):
     k, pods = 3, 8
     parts = partition_iid(ds.x, ds.y, k=k, seed=0)
     init = cnn.init_params(CFG, KEY)
-    ex = executor.MeshExecutor(mesh=_mesh(pods))
+    ex = executor.StackedExecutor(mesh=_mesh(pods), backend="mesh")
     plan = executor.ExecutionPlan(epochs=0, batch_size=32, seed=1000)
     ex.execute(CFG, init, parts, plan)
     gb = np.asarray(ex.e2lm_global_beta())
@@ -499,19 +502,17 @@ def test_hier_sync_and_reduce_lower_to_two_allreduces():
     topologies."""
     from repro.analysis.hlo import check_two_all_reduces
     mesh = _mesh2d(2, 4)
-    ex = executor.MeshExecutor(mesh=mesh)
-    ex._begin(CFG, 3)                                     # k_pad = 8
-    params_k = ex._place_params(cnn.init_params(CFG, KEY))
-    w = ex._weights_dev(None)
+    ex, params_k, _ = _placed(mesh, 3)                    # k_pad = 8
+    w = ex._weights(None)
 
-    sync = executor._mesh_sync.lower(mesh, params_k, w)
+    sync = _lower(mesh, executor._sync, params_k, w)
     check = check_two_all_reduces(sync)
     assert check.ok, check
 
     beta_k = jax.device_put(
         jnp.zeros((8, cnn.feature_dim(CFG), CFG.num_classes)),
         NamedSharding(mesh, P(("host", "pod"))))
-    red = executor._mesh_reduce.lower(mesh, (params_k, beta_k), w)
+    red = _lower(mesh, executor._reduce, (params_k, beta_k), w)
     check = check_two_all_reduces(red)
     assert check.ok, check
 
@@ -520,3 +521,27 @@ def test_hier_sync_and_reduce_lower_to_two_allreduces():
     # the flat 1-D audit still enforces ONE collective
     for report in audit_executor(CFG, "mesh", mesh=_mesh(8), k=3):
         assert report.ok, str(report)
+
+
+@pytest.mark.parametrize("pods", [4, 8])
+def test_member_init_on_a_mesh_matches_one_device(ds, pods):
+    """Per-member inits (the streaming runner's block continuation) on a
+    multi-device mesh, padded slots included (k=3): members, β and the
+    averaged model match the one-device run."""
+    cfg = replace(CFG, elm_lambda=1.0)
+    parts = partition_iid(ds.x, ds.y, k=3, seed=0)
+    inits = [cnn.init_params(cfg, jax.random.PRNGKey(i)) for i in range(3)]
+    plan = executor.ExecutionPlan(epochs=1, lr_schedule=dynamic_paper(0.05),
+                                  batch_size=32, member_init=inits)
+
+    def run(mesh):
+        out = executor.StackedExecutor(mesh=mesh).execute(
+            cfg, inits[0], parts, plan)
+        return out.members
+
+    one, many = run(_mesh(1)), run(_mesh(pods))
+    for a, b in zip(one, many):
+        for la, lb in zip(jax.tree.leaves((a.cnn_params, a.beta)),
+                          jax.tree.leaves((b.cnn_params, b.beta))):
+            np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
+                                       rtol=1e-5, atol=1e-6)

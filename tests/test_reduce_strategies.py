@@ -377,10 +377,9 @@ mesh_only = pytest.mark.skipif(
 
 @mesh_only
 def test_mesh_gossip_matches_stacked_and_audits_psum_free(ds):
-    from repro.analysis.hlo import audit_executor, ppermute_count
-    from repro.core import executor
+    from repro.analysis.hlo import (audit_executor, lower_stacked_programs,
+                                    ppermute_count)
     from repro.launch.mesh import make_member_mesh
-    from repro.models import cnn
 
     k, rounds = 8, 3
     parts = partition_iid(ds.x, ds.y, k=k, seed=0)
@@ -396,13 +395,10 @@ def test_mesh_gossip_matches_stacked_and_audits_psum_free(ds):
     reports = audit_executor(CFG, "mesh", mesh=mesh, k=k,
                              gossip_rounds=rounds)
     by_name = {r.program: r for r in reports}
-    assert by_name["mesh/_mesh_gossip_sync"].ok
-    ex = executor.MeshExecutor(mesh=mesh)
-    ex._begin(CFG, k)
-    params_k = ex._place_params(cnn.init_params(CFG, KEY))
-    hlo = executor._mesh_gossip_sync.lower(
-        ex.mesh, params_k, ex._weights_dev(None),
-        rounds=rounds).compile().as_text()
+    assert by_name["mesh/_gossip"].ok
+    _, programs = lower_stacked_programs(CFG, "mesh", mesh=mesh, k=k,
+                                         gossip_rounds=rounds)
+    hlo = programs["_gossip"].compile().as_text()
     assert ppermute_count(hlo) == 2 * rounds
     assert "all-reduce" not in hlo
 

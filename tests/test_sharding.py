@@ -66,7 +66,7 @@ def test_member_resolve_rules():
     assert sharding.resolve_spec((8, 5), ("member", None), pod8) == \
         P("pod", None)
     assert sharding.resolve_spec((16,), ("member",), pod8) == P("pod")
-    # 6 % 8 != 0 -> replicate (exactly why MeshExecutor pads 6 -> 8)
+    # 6 % 8 != 0 -> replicate (exactly why the stacked executor pads 6 -> 8)
     assert sharding.resolve_spec((6, 5), ("member", None), pod8) == \
         P(None, None)
     assert sharding.resolve_spec((8, 5), ("member", None), MESH) == \
@@ -78,8 +78,8 @@ def test_member_resolve_rules():
 
 
 def test_member_and_batch_specs_match_shardings():
-    """The spec-level twins (shard_map in/out_specs) must agree exactly
-    with the NamedSharding builders they mirror."""
+    """The spec-level twin (shard_map in/out_specs) must agree exactly
+    with ``member_dim_shardings``."""
     mesh = auto_mesh((1,), ("pod",))
     tree = {"w": jnp.zeros((4, 5, 3)), "b": jnp.zeros((4,))}
     specs = sharding.member_dim_specs(tree, mesh)
@@ -87,27 +87,6 @@ def test_member_and_batch_specs_match_shardings():
     assert specs == {"w": P("pod", None, None), "b": P("pod")}
     assert jax.tree.map(lambda s: s.spec, shardings_,
                         is_leaf=lambda x: hasattr(x, "spec")) == specs
-    batch = (jnp.zeros((2, 4, 8, 5, 5)), jnp.zeros((2, 4)))
-    bspecs = sharding.stacked_batch_specs(batch, mesh, member_axis=1)
-    bshard = sharding.stacked_batch_shardings(batch, mesh, member_axis=1)
-    assert bspecs == (P(None, "pod", None, None, None), P(None, "pod"))
-    assert tuple(s.spec for s in bshard) == bspecs
-
-
-def test_stacked_batch_shardings_member_axis():
-    """Scan-major batch arrays (nb, k, B, ...) shard the member dim (axis 1)
-    on 'pod' — the chunked host→device pipeline's placement — with the
-    usual replication fallback when k doesn't divide the pod count."""
-    mesh = auto_mesh((1,), ("pod",))
-    xb = jnp.zeros((4, 3, 8, 5, 5))
-    mb = jnp.zeros((4, 3))
-    out = sharding.stacked_batch_shardings((xb, mb), mesh)
-    assert out[0].spec == P(None, "pod", None, None, None)
-    assert out[1].spec == P(None, "pod")
-    # a mesh without a 'pod' axis replicates (the fallback contract)
-    mesh2 = auto_mesh((1,), ("data",))
-    out2 = sharding.stacked_batch_shardings((jnp.zeros((4, 5)),), mesh2)
-    assert out2[0].spec == P(None, None)
 
 
 @pytest.mark.parametrize("arch", LM_ARCHS)

@@ -15,7 +15,7 @@ import pytest
 
 from repro import scopes
 from repro.configs.base import get_reduced_config
-from repro.core import cnn_elm, elm
+from repro.core import cnn_elm, elm, executor
 from repro.core.executor import CheckpointConfig
 from repro.core.runner import AveragingRun, MapConfig, ReduceConfig
 from repro.data.partition import Partition
@@ -158,7 +158,7 @@ def test_elm_only_scan_keeps_no_record():
         out = jax.eval_shape(
             lambda *a: cnn_elm.stacked_epoch_scan(CFG, *a, **kw), *args[1:])
         assert len(out) == (3 if sgd else 2)
-        text = cnn_elm._stacked_epoch.lower(*args, **kw).compile().as_text()
+        text = executor._stacked_epoch.lower(*args, **kw).compile().as_text()
         written = re.findall(
             rf'dynamic-update-slice\(.*op_name="[^"]*/{scopes.STEP_RECORD}/',
             text)

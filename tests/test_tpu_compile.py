@@ -5,9 +5,10 @@ is described and not attached, so these tests catch what interpret mode
 cannot: Mosaic refusing a block shape, a kernel without a VJP, a
 ``pallas_call`` without ``vma`` inside ``shard_map``. Shapes are the
 published 6c-2s-12c-2s width at B=200 (and the conv kernel at every
-stage of both configs, and at serving's buckets). Each compiled program
-must hold the Pallas kernels (``tpu_custom_call``), so a silent fall back
-to the XLA conv fails here.
+stage of both configs, and at serving's buckets); the stacked executor's
+epoch compiles on the one-chip and on the four-chip member mesh. Each
+compiled program must hold the Pallas kernels (``tpu_custom_call``), so a
+silent fall back to the XLA conv fails here.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every pytest-xdist
@@ -24,7 +25,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.configs.base import get_config
-from repro.core import cnn_elm, elm, executor
+from repro.core import elm, executor
 from repro.kernels.conv2d import ops as conv_ops
 from repro.kernels.elm_stats.kernel import elm_stats
 from repro.models import cnn
@@ -106,137 +107,114 @@ def test_elm_stats_compile(one_chip, masked):
     assert _custom_calls(fn.lower(h, t, mask).compile()) == 1
 
 
-@pytest.mark.parametrize("masked", [False, True])
-def test_stacked_epoch_with_sgd_compiles(one_chip, masked):
+def _member_mesh(topo, chips: int):
+    """The member mesh the stacked executor builds over ``chips`` of the
+    described chips, and its placements: member dim first, member dim
+    second (scan-major batches), replicated."""
+    mesh = Mesh(np.asarray(topo.devices[:chips]).reshape(chips), ("pod",),
+                axis_types=(AxisType.Auto,))
+    return (mesh,) + tuple(NamedSharding(mesh, spec)
+                           for spec in (P("pod"), P(None, "pod"), P()))
+
+
+def _no_collectives(text: str) -> bool:
+    return not any(c in text for c in ("all-reduce", "all-gather",
+                                       "all-to-all", "collective-permute"))
+
+
+@pytest.mark.parametrize("chips,masked", [(1, False), (1, True), (4, False)])
+def test_stacked_epoch_with_sgd_compiles(topo, chips, masked):
     """Alg. 2 lines 9-14 for k=4 members: features, stats, β solve and the
-    SGD backward through the conv kernel, one scan."""
-    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), one_chip),
-                         _sds((K_MEMBERS, F, C), one_chip),
-                         _sds((K_MEMBERS,), one_chip))
-    lowered = cnn_elm._stacked_epoch.lower(
-        CFG, _member_params(one_chip), stats,
-        _sds((NB, K_MEMBERS, B, 28, 28), one_chip),
-        _sds((NB, K_MEMBERS, B, C), one_chip),
-        _sds((NB, K_MEMBERS), one_chip), _sds((), one_chip),
-        solve_each_batch=True, use_pallas=True, masked=masked)
+    SGD backward through the conv kernel, one scan — on the one-chip
+    member mesh, and on four chips, one member a chip, ``shard_map``-ed
+    with ``check_vma`` on (the kernels need ``vma`` on their out_shapes)
+    and free of collectives (members are independent)."""
+    mesh, member, batch, repl = _member_mesh(topo, chips)
+    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), member),
+                         _sds((K_MEMBERS, F, C), member),
+                         _sds((K_MEMBERS,), member))
+    with jax.set_mesh(mesh):
+        lowered = executor._stacked_epoch.lower(
+            CFG, _member_params(member), stats,
+            _sds((NB, K_MEMBERS, B, 28, 28), batch),
+            _sds((NB, K_MEMBERS, B, C), batch),
+            _sds((NB, K_MEMBERS), batch), _sds((), repl),
+            solve_each_batch=True, use_pallas=True, masked=masked)
     # 2 conv forwards for the stats, 2 for the loss, the backward's dX of
     # conv2 and dW of both (conv1's dX is dead and pruned), 1 elm_stats
     compiled = lowered.compile()
+    text = compiled.as_text()
     assert _custom_calls(compiled) >= 7
     # every kernel, the backward ones too, lies under its device scope:
     # the chip's trace names the op-name path of each op it runs
-    kernels = [ln for ln in compiled.as_text().splitlines()
+    kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert kernels and all(re.search(r'op_name="[^"]*/(conv2d|elm_stats)/',
                                      ln) for ln in kernels)
     for scope in ("beta_solve", "sgd_update"):
-        assert f"({scope})/" in compiled.as_text()
+        assert f"({scope})/" in text
+    assert _no_collectives(text)
 
 
-def test_mesh_epoch_compiles_on_four_chips(topo):
-    """The mesh backend's epoch, shard_map-ed over 4 described chips with
-    ``check_vma`` on: the kernels need ``vma`` on their out_shapes."""
-    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("pod",),
-                axis_types=(AxisType.Auto,))
-    pod = NamedSharding(mesh, P("pod"))
-    per_batch = NamedSharding(mesh, P(None, "pod"))
-    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), pod),
-                         _sds((K_MEMBERS, F, C), pod),
-                         _sds((K_MEMBERS,), pod))
-    lowered = executor._mesh_epoch.lower(
-        CFG, mesh, _member_params(pod), stats,
-        _sds((NB, K_MEMBERS, B, 28, 28), per_batch),
-        _sds((NB, K_MEMBERS, B, C), per_batch),
-        _sds((NB, K_MEMBERS), per_batch),
-        _sds((), NamedSharding(mesh, P())),
-        solve_each_batch=True, use_pallas=True, masked=False)
-    compiled = lowered.compile()
-    assert _custom_calls(compiled) >= 7
-    assert "all-reduce" not in compiled.as_text()     # members independent
+def _slot_rows(n, chips, place):
+    """The partitions as ``StackedExecutor._put_partitions`` places them:
+    one array of chips·n flat rows per member slot, and the labels."""
+    slots = K_MEMBERS // chips
+    return (tuple(_sds((chips * n, 28 * 28), place) for _ in range(slots)),
+            tuple(_sds((chips * n,), place, jnp.int32)
+                  for _ in range(slots)))
 
 
-def test_gathered_epoch_compiles_at_the_elm_cell_size(one_chip):
+@pytest.mark.parametrize("chips", [1, 4])
+def test_gathered_epoch_compiles_at_the_elm_cell_size(topo, chips):
     """The device-built epoch at the ELM-only cell's shapes (3c-9c, k=4
     members of 60,000 rows, 300 batches of 200): the gather in front of
     the scan compiles with the kernels, and the partitions, the gathered
-    epoch and the scan fit well inside one chip's 16 GB."""
+    epoch and the scan fit well inside one chip's 16 GB. On four chips
+    each chip gathers its own member's batches: no collective moves a
+    row."""
     cfg = get_config("cnn_elm_3c9c")
     n, nb = 60000, 300
     f, c = cnn.feature_dim(cfg), cfg.num_classes
+    mesh, member, batch, repl = _member_mesh(topo, chips)
     shapes = jax.eval_shape(lambda: cnn.init_params(cfg,
                                                     jax.random.PRNGKey(0)))
-    params = jax.tree.map(lambda a: _sds((K_MEMBERS,) + a.shape, one_chip),
+    params = jax.tree.map(lambda a: _sds((K_MEMBERS,) + a.shape, member),
                           shapes)
-    stats = elm.ELMStats(_sds((K_MEMBERS, f, f), one_chip),
-                         _sds((K_MEMBERS, f, c), one_chip),
-                         _sds((K_MEMBERS,), one_chip))
-    xs = tuple(_sds((n, 28, 28), one_chip) for _ in range(K_MEMBERS))
-    ys = tuple(_sds((n,), one_chip, jnp.int32) for _ in range(K_MEMBERS))
-    idx = _sds((nb, K_MEMBERS, B), one_chip, jnp.int32)
-    compiled = cnn_elm._stacked_epoch.lower(
-        cfg, params, stats, idx, idx, _sds((nb, K_MEMBERS), one_chip),
-        _sds((), one_chip), solve_each_batch=False, use_pallas=True,
-        masked=False, rows=(xs, ys)).compile()
+    stats = elm.ELMStats(_sds((K_MEMBERS, f, f), member),
+                         _sds((K_MEMBERS, f, c), member),
+                         _sds((K_MEMBERS,), member))
+    idx = _sds((nb, K_MEMBERS, B), batch, jnp.int32)
+    with jax.set_mesh(mesh):
+        compiled = executor._stacked_epoch.lower(
+            cfg, params, stats, idx, idx, _sds((nb, K_MEMBERS), batch),
+            _sds((), repl), solve_each_batch=False, use_pallas=True,
+            masked=False, rows=_slot_rows(n, chips, member)).compile()
     assert _custom_calls(compiled) >= 3       # two convs, elm_stats
+    assert _no_collectives(compiled.as_text())
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
 
 
-def test_mesh_gathered_epoch_compiles_on_four_chips(topo):
-    """Each chip gathers its own members' batches inside the shard_map:
-    the partitions are member-sharded, so no collective moves a row."""
-    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("pod",),
-                axis_types=(AxisType.Auto,))
-    pod = NamedSharding(mesh, P("pod"))
-    per_batch = NamedSharding(mesh, P(None, "pod"))
-    stats = elm.ELMStats(_sds((K_MEMBERS, F, F), pod),
-                         _sds((K_MEMBERS, F, C), pod),
-                         _sds((K_MEMBERS,), pod))
-    idx = _sds((NB, K_MEMBERS, B), per_batch, jnp.int32)
-    rows = (_sds((K_MEMBERS, 1000, 28, 28), pod),
-            _sds((K_MEMBERS, 1000), pod, jnp.int32))
-    lowered = executor._mesh_epoch.lower(
-        CFG, mesh, _member_params(pod), stats, idx, idx,
-        _sds((NB, K_MEMBERS), per_batch), _sds((), NamedSharding(mesh, P())),
-        solve_each_batch=True, use_pallas=True, masked=True, rows=rows)
-    compiled = lowered.compile()
-    assert _custom_calls(compiled) >= 7
-    text = compiled.as_text()
-    assert not any(c in text for c in ("all-reduce", "all-gather",
-                                       "all-to-all", "collective-permute"))
-
-
-@pytest.mark.parametrize("form", ["stacked", "mesh"])
-def test_sgd_epoch_with_its_step_record_compiles(topo, form):
+@pytest.mark.parametrize("chips", [1, 4])
+def test_sgd_epoch_with_its_step_record_compiles(topo, chips):
     """The SGD cell's epoch (6c-12c, k=4 members of 60,000 rows, 300
     batches of 200, gathered on the device) with its step record: every
     member's params at the start of every step, (300, 4, ...) per leaf,
     written under the ``step_record`` scope; on four chips member-sharded
     on its second dim, one member a chip."""
     n, nb = 60000, 300
-    if form == "stacked":
-        place = member = batch = SingleDeviceSharding(topo.devices[0])
-        xs = tuple(_sds((n, 28 * 28), place) for _ in range(K_MEMBERS))
-        ys = tuple(_sds((n,), place, jnp.int32) for _ in range(K_MEMBERS))
-        lower, head = cnn_elm._stacked_epoch.lower, (CFG,)
-    else:
-        mesh = Mesh(np.asarray(topo.devices).reshape(4), ("pod",),
-                    axis_types=(AxisType.Auto,))
-        place = NamedSharding(mesh, P())
-        member = NamedSharding(mesh, P("pod"))
-        batch = NamedSharding(mesh, P(None, "pod"))
-        xs = _sds((K_MEMBERS, n, 28 * 28), member)
-        ys = _sds((K_MEMBERS, n), member, jnp.int32)
-        lower, head = executor._mesh_epoch.lower, (CFG, mesh)
+    mesh, member, batch, repl = _member_mesh(topo, chips)
     stats = elm.ELMStats(_sds((K_MEMBERS, F, F), member),
                          _sds((K_MEMBERS, F, C), member),
                          _sds((K_MEMBERS,), member))
     idx = _sds((nb, K_MEMBERS, B), batch, jnp.int32)
-    lowered = lower(
-        *head, _member_params(member), stats, idx, idx,
-        _sds((nb, K_MEMBERS), batch),
-        _sds((), place), solve_each_batch=True, use_pallas=True,
-        masked=False, rows=(xs, ys))
+    with jax.set_mesh(mesh):
+        lowered = executor._stacked_epoch.lower(
+            CFG, _member_params(member), stats, idx, idx,
+            _sds((nb, K_MEMBERS), batch), _sds((), repl),
+            solve_each_batch=True, use_pallas=True, masked=False,
+            rows=_slot_rows(n, chips, member))
     params, _, record = lowered.out_info
     for p, r in zip(jax.tree.leaves(params), jax.tree.leaves(record)):
         assert r.shape == (nb,) + p.shape
@@ -244,11 +222,10 @@ def test_sgd_epoch_with_its_step_record_compiles(topo, form):
     text = compiled.as_text()
     assert _custom_calls(compiled) >= 7
     assert re.search(r'op_name="[^"]*/step_record/', text)
-    if form == "mesh":
-        assert not any(c in text for c in ("all-reduce", "all-gather"))
+    assert _no_collectives(text)
+    if chips > 1:
         assert all(s.spec[:2] == (None, "pod") for s in jax.tree.leaves(
             compiled.output_shardings[2]))
-    else:
-        # the rows, the scan and a 9.4 MB record: well inside 16 GB
-        mem = compiled.memory_analysis()
-        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+    # the rows, the scan and a 9.4 MB record: well inside 16 GB a chip
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30

@@ -14,7 +14,7 @@ import pytest
 from repro import scopes
 from repro.analysis.hlo import _tiny_inputs
 from repro.configs.base import get_reduced_config
-from repro.core import cnn_elm, elm
+from repro.core import cnn_elm, elm, executor
 from repro.core.averaging import broadcast_member_dim
 from repro.core.runner import AveragingRun, MapConfig, ReduceConfig
 from repro.data.partition import partition_iid
@@ -51,7 +51,7 @@ def test_epoch_program_ops_carry_scopes(sgd):
     params = cnn.init_params(CFG, jax.random.PRNGKey(0))
     F, C = cnn.feature_dim(CFG), CFG.num_classes
     xb, tb, mb = _tiny_inputs(CFG, K, 4, 2)
-    text = cnn_elm._stacked_epoch.lower(
+    text = executor._stacked_epoch.lower(
         CFG, broadcast_member_dim(params, K), elm.zero_stats_stacked(K, F, C),
         jnp.asarray(xb), jnp.asarray(tb), jnp.asarray(mb), jnp.float32(0.1),
         solve_each_batch=sgd, use_pallas=False,
@@ -71,11 +71,11 @@ def test_gathered_epoch_ops_carry_scopes(sgd):
     params = cnn.init_params(CFG, jax.random.PRNGKey(0))
     F, C = cnn.feature_dim(CFG), CFG.num_classes
     n, nb, B = 40, 2, 4
-    xs = tuple(jnp.zeros((n, CFG.image_size, CFG.image_size))
+    xs = tuple(jnp.zeros((n, CFG.image_size * CFG.image_size))
                for _ in range(K))
     ys = tuple(jnp.zeros((n,), jnp.int32) for _ in range(K))
     idx = jnp.zeros((nb, K, B), jnp.int32)
-    text = cnn_elm._stacked_epoch.lower(
+    text = executor._stacked_epoch.lower(
         CFG, broadcast_member_dim(params, K), elm.zero_stats_stacked(K, F, C),
         idx, idx, jnp.ones((nb, K)), jnp.float32(0.1), solve_each_batch=sgd,
         use_pallas=False, masked=True, rows=(xs, ys)).compile().as_text()
